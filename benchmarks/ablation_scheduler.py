@@ -19,6 +19,7 @@ from typing import Dict
 from repro.core import schedule as S
 from repro.core.heft import heft_solution
 from repro.core.rewrite import rewrite
+from repro.launch.cache import enable_compile_cache
 from repro.models import edge
 from repro.soc.carfield import carfield_patterns, carfield_soc
 
@@ -51,6 +52,7 @@ def run(verbose: bool = True) -> Dict[str, Dict[str, float]]:
 
 
 def main() -> None:
+    enable_compile_cache()
     run()
 
 

@@ -6,6 +6,7 @@ from __future__ import annotations
 from typing import Dict, List
 
 from repro.core.api import compile_model
+from repro.launch.cache import enable_compile_cache
 from repro.models import edge
 from repro.soc.carfield import carfield_patterns, carfield_soc
 
@@ -50,6 +51,7 @@ def run(verbose: bool = True) -> Dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     run()
 
 

@@ -13,6 +13,7 @@ from typing import Dict, List
 
 from repro.core.api import compile_model
 from repro.core.runtime import plan_matches_oracle
+from repro.launch.cache import enable_compile_cache
 from repro.models import edge
 from repro.soc.carfield import carfield_patterns, carfield_soc
 
@@ -53,6 +54,7 @@ def run(check_numerics: bool = True, verbose: bool = True) -> List[Dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     print("block,mode,cycles,flops")
     for r in run(verbose=False):
         print(f"{r['block']},{r['mode']},{r['cycles']:.0f},{r['flops']:.3e}")

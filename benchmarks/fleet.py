@@ -48,6 +48,7 @@ from repro.fleet import (ContentionModel, FailureEvent, Fleet, FleetConfig,
                          balanced_utilization, default_demand,
                          place_contention_aware, place_random,
                          place_round_robin, replay_open_loop)
+from repro.launch.cache import enable_compile_cache
 from repro.models import edge
 from repro.serve.admission import Priority
 from repro.soc.carfield import carfield_patterns, carfield_soc
@@ -398,6 +399,7 @@ def run(n_socs: int = 16, capacity: int = 2, duration_rounds: int = 60,
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--socs", type=int, default=16,
                     help="fleet size (default 16; the paper-scale sweep "

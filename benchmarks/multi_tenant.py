@@ -74,6 +74,7 @@ import time
 from repro.core.api import compile_multi
 from repro.core.runtime import multi_plan_matches_oracle
 from repro.core.schedule import _search_coschedule, default_budgets
+from repro.launch.cache import enable_compile_cache
 from repro.models import edge
 from repro.serve.admission import Priority, RoundComposer
 from repro.serve.compiler_thread import BackgroundCompiler
@@ -763,6 +764,7 @@ def run_compile_pipeline(verbose: bool = True, time_budget_s: float = 1.0,
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="skip the numeric allclose re-validation")

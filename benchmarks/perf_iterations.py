@@ -30,6 +30,7 @@ from typing import Dict, List, Optional  # noqa: E402
 from repro.configs import registry                      # noqa: E402
 from repro.configs.shapes import SHAPES                  # noqa: E402
 from repro.launch import dryrun                          # noqa: E402
+from repro.launch.cache import enable_compile_cache       # noqa: E402
 from repro.launch.mesh import make_production_mesh       # noqa: E402
 
 PEAK_FLOPS = 197e12
@@ -143,6 +144,7 @@ EXPERIMENTS = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(EXPERIMENTS))
     ap.add_argument("--out", default="artifacts/perf_iterations.json")

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 from repro.configs import registry
 from repro.configs.shapes import SHAPES
 from repro.core.hbmplan import param_count
+from repro.launch.cache import enable_compile_cache
 
 PEAK_FLOPS = 197e12      # TPU v5e bf16 per chip
 HBM_BW = 819e9           # bytes/s per chip
@@ -127,6 +128,7 @@ def table(rows: List[Dict]) -> str:
 
 
 def main() -> None:
+    enable_compile_cache()
     rows = analyze()
     print(table(rows))
     # summary picks for the §Perf hillclimb

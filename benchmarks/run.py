@@ -18,8 +18,11 @@ import argparse
 import os
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="skip the numeric allclose re-validation")

@@ -35,6 +35,7 @@ import json
 import random
 
 from repro.core.deploy import CompileRequest, DeploymentSession
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm_graphs import lm_tenant
 from repro.serve.admission import (AdmissionController, ClassPolicy,
                                    Priority, RoundComposer)
@@ -157,6 +158,7 @@ def run(n_prompts: int = 3, decode_steps: int = 6) -> dict:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--json", default=None,
                     help="write the report to this path")

@@ -15,6 +15,7 @@ from typing import Dict, List
 
 from repro.core.api import compile_model
 from repro.core.runtime import plan_matches_oracle
+from repro.launch.cache import enable_compile_cache
 from repro.models import edge
 from repro.soc.carfield import carfield_patterns, carfield_soc
 
@@ -64,6 +65,7 @@ def run(check_numerics: bool = True, verbose: bool = True) -> List[Dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     print("model,mode,macs,params,cycles,runtime_ms,flops,paper_ms")
     for r in run(verbose=False):
         print(f"{r['model']},{r['mode']},{r['macs']},{r['params']},"

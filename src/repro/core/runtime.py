@@ -15,7 +15,12 @@ benchmark model and every toolchain mode.
 
 Everything here runs in float32 regardless of the deployment dtype: the
 numerics validate *plan structure* (tiling, halos, segment stitching), not
-reduced-precision kernels.
+reduced-precision kernels.  Every conv and matmul passes
+``precision=PRECISION`` (``HIGHEST``): a TPU otherwise computes a float32
+conv or matmul from bfloat16 inputs, and the ``1e-4`` oracle tolerances
+would mean something different on the chip than on the CPU.  Moving the
+device path to the deployment dtype (ROADMAP Speed item 5) replaces this
+with per-dtype tolerances.
 """
 
 from __future__ import annotations
@@ -33,6 +38,8 @@ from repro.core.rewrite import Supernode, TiledGraph
 from repro.core.schedule import ExecutionPlan
 
 Arrays = Dict[str, jnp.ndarray]
+
+PRECISION = lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -81,6 +88,23 @@ def _pad_nhwc(x: jnp.ndarray, kh: int, kw: int, stride: int,
     return jnp.pad(x, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
 
 
+def _conv(ot: str, xp: jnp.ndarray, w: jnp.ndarray,
+          stride: int) -> jnp.ndarray:
+    """VALID conv2d / dwconv2d of an already padded NHWC input."""
+    groups = xp.shape[-1] if ot == "dwconv2d" else 1
+    if ot == "dwconv2d":
+        # HWIO with I=1: reshape to (kh, kw, 1, C*mult) grouped conv
+        w = w.reshape(w.shape[0], w.shape[1], 1, -1)
+    return lax.conv_general_dilated(
+        xp, w, window_strides=(stride, stride), padding="VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=groups, precision=PRECISION)
+
+
+def _matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    return jnp.matmul(x, w, precision=PRECISION)
+
+
 def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
     a = op.attrs
     ot = op.op_type
@@ -88,20 +112,10 @@ def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
         x, w = ins[0], ins[1]
         stride = a.get("stride", 1)
         padding = a.get("padding", "same")
-        kh, kw = w.shape[0], w.shape[1]
-        xp = _pad_nhwc(x, kh, kw, stride, padding)
-        groups = x.shape[-1] if ot == "dwconv2d" else 1
-        if ot == "dwconv2d":
-            # HWIO with I=1: reshape to (kh, kw, 1, C*mult) grouped conv
-            w = w.reshape(kh, kw, 1, -1)
-        return lax.conv_general_dilated(
-            xp, w, window_strides=(stride, stride), padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=groups)
-    if ot == "dense":
-        return jnp.matmul(ins[0], ins[1])
-    if ot in ("matmul", "batch_matmul"):
-        return jnp.matmul(ins[0], ins[1])
+        xp = _pad_nhwc(x, w.shape[0], w.shape[1], stride, padding)
+        return _conv(ot, xp, w, stride)
+    if ot in ("dense", "matmul", "batch_matmul"):
+        return _matmul(ins[0], ins[1])
     if ot == "add":
         return ins[0] + ins[1]
     if ot == "sub":
@@ -216,14 +230,7 @@ def _conv_row_tile(g: Graph, op: Op, ins: Sequence[jnp.ndarray],
         xp = _pad_nhwc(x, kh, kw, stride, padding)
         i0 = r0 * stride
         i1 = (r1 - 1) * stride + kh
-        xs = xp[:, i0:i1, :, :]
-        groups = x.shape[-1] if ot == "dwconv2d" else 1
-        if ot == "dwconv2d":
-            w = w.reshape(kh, kw, 1, -1)
-        return lax.conv_general_dilated(
-            xs, w, window_strides=(stride, stride), padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=groups)
+        return _conv(ot, xp[:, i0:i1, :, :], w, stride)
     if ot in ("avg_pool2d", "max_pool2d"):
         k = a["pool_size"]
         s = a.get("stride", k)
@@ -273,7 +280,7 @@ def run_supernode(g: Graph, sn: Supernode, env: Arrays) -> Dict[str, jnp.ndarray
             elif op.op_type in ("dense", "matmul", "batch_matmul"):
                 assert prev_tile is None, "gemm must head its chain"
                 x, w = ins_full[0], ins_full[1]
-                tile = jnp.matmul(x, _slice_axis(w, w.ndim - 1, c0, c1))
+                tile = _matmul(x, _slice_axis(w, w.ndim - 1, c0, c1))
             else:
                 # elementwise / normalization: slice every full input along
                 # the tile axis; 1-D bias broadcasts slice on the last axis

@@ -1,13 +1,17 @@
 """Kernel dispatch policy.
 
-On the TPU target the Pallas kernels are the production path; this CPU
-container validates them in interpret mode and uses the jnp references for
-everything that must actually *run* (smoke tests, examples) or *lower*
-(the multi-pod dry-run lowers for the CPU backend, where custom TPU kernels
-are unavailable).  Policy:
+On a TPU the Pallas kernels are the production path and run compiled.
+Elsewhere the models use the pure-jnp references, which run and lower on
+every backend (the multi-pod dry-run lowers for the CPU, where Mosaic
+kernels cannot run); the tests drive the kernels on the CPU in interpret
+mode.  Policy:
 
-  * default: pure-jnp reference (fast, exact, lowers everywhere);
-  * ``REPRO_USE_PALLAS=1``: Pallas kernels, interpret mode iff not on TPU.
+  * default: Pallas on a TPU, the jnp reference elsewhere;
+  * ``REPRO_USE_PALLAS=1`` / ``=0`` forces the choice; off a TPU the
+    kernels then run in interpret mode.
+
+Backend detection raises what JAX raises: a process that cannot reach
+its backend fails rather than being moved to the reference path.
 """
 
 from __future__ import annotations
@@ -18,10 +22,7 @@ import jax
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas() -> bool:
@@ -33,14 +34,3 @@ def use_pallas() -> bool:
 
 def interpret() -> bool:
     return not on_tpu()
-
-
-def tpu_compiler_params(**kwargs):
-    """Construct pallas TPU compiler params across jax versions: the class
-    was ``CompilerParams`` before 0.4.31, ``TPUCompilerParams`` through the
-    0.4/0.5 line (the baked-in toolchain), and ``CompilerParams`` again in
-    newer releases."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "TPUCompilerParams", None) \
-        or getattr(pltpu, "CompilerParams")
-    return cls(**kwargs)

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.config import tpu_compiler_params
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref):
     ki = pl.program_id(3)
@@ -52,7 +50,7 @@ def grouped_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -17,30 +17,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.config import tpu_compiler_params
 
-
-def _rglru_kernel(a_ref, b_ref, h_ref, hout_ref, state_ref, *, L: int):
+def _rglru_kernel(a_ref, b_ref, h_ref, hout_ref, state_ref, a_s, b_s, ys_s,
+                  *, L: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    a = a_ref[0].astype(jnp.float32)          # (L, bd)
-    b = b_ref[0].astype(jnp.float32)
+    # f32 copies in scratch: the loop reads and writes one (1, bd) row per
+    # step through ref slices, which Mosaic lowers (value indexing it
+    # does not)
+    a_s[...] = a_ref[0].astype(jnp.float32)   # (L, bd)
+    b_s[...] = b_ref[0].astype(jnp.float32)
 
-    def step(t, carry):
-        h, ys = carry
-        h = a[t] * h + b[t]
-        ys = jax.lax.dynamic_update_index_in_dim(ys, h, t, 0)
-        return h, ys
+    def step(t, h):
+        h = a_s[pl.ds(t, 1), :] * h + b_s[pl.ds(t, 1), :]
+        ys_s[pl.ds(t, 1), :] = h
+        return h
 
-    h0 = state_ref[0]                          # (bd,)
-    ys0 = jnp.zeros_like(a)
-    hT, ys = jax.lax.fori_loop(0, L, step, (h0, ys0))
-    h_ref[0] = ys.astype(h_ref.dtype)
-    state_ref[0, :] = hT
+    hT = jax.lax.fori_loop(0, L, step, state_ref[...])   # (1, bd)
+    h_ref[0] = ys_s[...].astype(h_ref.dtype)
+    state_ref[...] = hT
 
     @pl.when(ci == pl.num_programs(1) - 1)
     def _done():
@@ -59,9 +58,7 @@ def rglru_pallas(a: jnp.ndarray, b: jnp.ndarray, chunk: int = 64,
     grid = (B * (D // bd), T // L)
     nd = D // bd
 
-    af = a.transpose(0, 2, 1).reshape(B * nd, bd, T).transpose(0, 2, 1) \
-        if False else a.reshape(B, T, nd, bd).transpose(0, 2, 1, 3) \
-        .reshape(B * nd, T, bd)
+    af = a.reshape(B, T, nd, bd).transpose(0, 2, 1, 3).reshape(B * nd, T, bd)
     bf = b.reshape(B, T, nd, bd).transpose(0, 2, 1, 3).reshape(B * nd, T, bd)
 
     h, hT = pl.pallas_call(
@@ -70,11 +67,16 @@ def rglru_pallas(a: jnp.ndarray, b: jnp.ndarray, chunk: int = 64,
         in_specs=[pl.BlockSpec((1, L, bd), lambda g, c: (g, c, 0)),
                   pl.BlockSpec((1, L, bd), lambda g, c: (g, c, 0))],
         out_specs=[pl.BlockSpec((1, L, bd), lambda g, c: (g, c, 0)),
-                   pl.BlockSpec((1, bd), lambda g, c: (g, 0))],
+                   pl.BlockSpec((1, 1, bd), lambda g, c: (g, 0, 0))],
+        # h_last carries a unit middle axis so its block's last two dims
+        # (1, bd) equal the array's and are (8, 128)-legal on the chip
         out_shape=[jax.ShapeDtypeStruct((B * nd, T, bd), a.dtype),
-                   jax.ShapeDtypeStruct((B * nd, bd), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+                   jax.ShapeDtypeStruct((B * nd, 1, bd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32),
+                        pltpu.VMEM((L, bd), jnp.float32),
+                        pltpu.VMEM((L, bd), jnp.float32),
+                        pltpu.VMEM((L, bd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(af, bf)

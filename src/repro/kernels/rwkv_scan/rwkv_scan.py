@@ -28,7 +28,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.config import tpu_compiler_params
+# Every dot of the kernel runs at full f32 precision.  r~ and k~ carry the
+# decay rescaling (A_{t-1} and 1/A_t span many orders of magnitude), and at
+# Mosaic's default precision a v5e rounded them so far that y was off by
+# 0.5 at rwkv6-3b widths; the log-decay prefix sums need it as well.
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref,
@@ -45,29 +49,42 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref,
     w = w_ref[0].astype(jnp.float32)
     u = u_ref[0].astype(jnp.float32)          # (1, D) -> broadcast
 
+    ti = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     logw = jnp.log(jnp.maximum(w, 1e-20))
-    logA = jnp.cumsum(logw, axis=0)           # (L, D): log prod_{s<=t}
-    A = jnp.exp(logA)
+    # within-chunk prefix sum as a product with the lower-triangular ones
+    # matrix (Mosaic has no cumsum)
+    tril = jnp.where(ti >= si, 1.0, 0.0).astype(jnp.float32)
+    logA = jax.lax.dot_general(tril, logw, (((1,), (0,)), ((), ())),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
     A_prev = jnp.exp(logA - logw)             # A_{t-1} = A_t / w_t
     r_t = r * A_prev
     k_t = k * jnp.exp(-logA)
 
     s = jax.lax.dot_general(r_t, k_t, (((1,), (1,)), ((), ())),
+                            precision=_HI,
                             preferred_element_type=jnp.float32)  # (L, L)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     s = jnp.where(ti > si, s, 0.0)            # strictly lower triangular
     diag = jnp.sum(r * (u * k), axis=1)       # (L,)
     y = jax.lax.dot_general(s, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+                            precision=_HI, preferred_element_type=jnp.float32)
     y += diag[:, None] * v
     y += jax.lax.dot_general(r_t, s_ref[...], (((1,), (0,)), ((), ())),
+                             precision=_HI,
                              preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
     ktv = jax.lax.dot_general(k_t, v, (((0,), (0,)), ((), ())),
+                              precision=_HI,
                               preferred_element_type=jnp.float32)  # (D, D)
-    s_ref[...] = A[-1][:, None] * (s_ref[...] + ktv)
+    # log A_{L-1} as a (D, 1) column: the chunk's column sums of log w,
+    # contracted on the MXU (no row indexing or transpose in the kernel)
+    log_a_last = jax.lax.dot_general(
+        logw, jnp.ones((L, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=_HI,
+        preferred_element_type=jnp.float32)
+    s_ref[...] = jnp.exp(log_a_last) * (s_ref[...] + ktv)
 
     @pl.when(ci == pl.num_programs(1) - 1)
     def _done():
@@ -108,7 +125,7 @@ def wkv6_pallas(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             jax.ShapeDtypeStruct((BH, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf)

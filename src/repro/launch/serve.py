@@ -18,6 +18,7 @@ from __future__ import annotations
 import argparse
 
 from repro.core.deploy import CompileRequest, DeploymentSession
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm_graphs import LM_FAMILIES, lm_tenant
 from repro.serve.compiler_thread import BackgroundCompiler
 from repro.serve.engine import MultiModelEngine
@@ -78,6 +79,7 @@ def serve(lm: str = "rwkv6", n_prompts: int = 4, decode_steps: int = 8,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--lm", default="rwkv6",
                     choices=sorted(LM_FAMILIES))

@@ -22,6 +22,7 @@ from repro.configs import registry
 from repro.core import meshplan
 from repro.data.pipeline import DataConfig, Pipeline
 from repro.fault.supervisor import Supervisor, SupervisorConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import get_model
 from repro.optim import adamw
@@ -81,6 +82,7 @@ def train(arch: str, steps: int = 50, batch: int = 8, seq: int = 128,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=registry.ARCH_IDS)
     ap.add_argument("--steps", type=int, default=50)

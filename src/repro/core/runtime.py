@@ -35,7 +35,8 @@ from jax import lax
 
 from repro.core.ir import Graph, Op, tile_axis
 from repro.core.rewrite import Supernode, TiledGraph
-from repro.core.schedule import ExecutionPlan
+from repro.core.schedule import ExecutionPlan, PlanNode
+from repro.core.spans import KERNEL, span
 
 Arrays = Dict[str, jnp.ndarray]
 
@@ -355,6 +356,12 @@ class _TenantExecutor:
         return {t: self.env[t] for t in self.g.outputs}
 
 
+def _kernel_span(ex: _TenantExecutor, n: PlanNode):
+    """The ``repro.kernel`` span of plan node ``n``, run by ``ex``."""
+    return span(KERNEL, tenant=ex.g.name, supernode=n.supernode,
+                resource=n.resource, analytic_cycles=n.duration)
+
+
 def execute_plan(plan: ExecutionPlan, inputs: Arrays, params: Arrays
                  ) -> Arrays:
     """Tile-by-tile execution following the compiled plan.
@@ -366,7 +373,8 @@ def execute_plan(plan: ExecutionPlan, inputs: Arrays, params: Arrays
     for node_name in plan.order:
         n = plan.nodes[node_name]
         if n.kind == "kernel" and n.supernode is not None:
-            ex.run_kernel(n.supernode)
+            with _kernel_span(ex, n):
+                ex.run_kernel(n.supernode)
     return ex.outputs()
 
 
@@ -385,7 +393,9 @@ def execute_multi_plan(plan, inputs_list: Sequence[Arrays],
     for node_name in plan.order:
         n = plan.nodes[node_name]
         if n.kind == "kernel" and n.supernode is not None:
-            execs[n.tenant].run_kernel(n.supernode)
+            ex = execs[n.tenant]
+            with _kernel_span(ex, n):
+                ex.run_kernel(n.supernode)
     return [ex.outputs() for ex in execs]
 
 

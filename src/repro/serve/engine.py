@@ -65,6 +65,8 @@ from typing import Any, Dict, List, Mapping, Optional
 
 import numpy as np
 
+from repro.core.spans import (EXECUTE, PLAN, STEP, SUBMIT, WAVE, joined,
+                              span)
 from repro.serve.admission import (AdmissionController, Priority,
                                    RoundComposer, RoundPlanProbe,
                                    TenantView)
@@ -250,67 +252,70 @@ class MultiModelEngine:
         off the dispatch path.  Returns the request id, or ``None`` when
         admission rejected the request (recorded in ``rejected``)."""
         tenant = self.resolve(model)
-        priority = Priority(priority)
-        bucket = self._resolve_bucket(tenant, seq_len)
-        if arrival_s is not None:
-            self.advance_clock(arrival_s)
-        submit_s = arrival_s if arrival_s is not None else self.clock_s
-        self.class_submitted[priority] += 1
-        rid = self._next_rid
-        self._next_rid += 1
-        if (self.admission is not None
-                and not self.admission.admit(priority,
-                                             self._class_depths())):
-            # rejected before any input generation; no arrays retained
-            self.rejected.append(
-                InferRequest(rid, tenant, None, self._round,
-                             priority=priority, deadline_s=deadline_s,
-                             submit_s=submit_s,
-                             depth_at_submit=len(self.queues[tenant]),
-                             seq_len=seq_len, bucket=bucket,
-                             deadline_abs_override_s=deadline_abs_s))
-            return None
-        if (priority != Priority.NORMAL or deadline_s is not None
-                or deadline_abs_s is not None):
-            # only ADMITTED SLO traffic ends the zero-cost FIFO
-            # short-circuit — a rejected request never enters a queue
-            self._slo_seen = True
-        if bucket is not None:
-            # price the request's floor before it can be dispatched (and
-            # never inside a round): compile-alone at the bucket
-            self.session.bucket_single(tenant, bucket)
-        if inputs is None and self.execute:
-            from repro.core.runtime import init_inputs
-            g = (self.session.bucket_graph(tenant, bucket)
-                 if bucket is not None else self.compiled.graphs[tenant])
-            inputs = init_inputs(g, seed + rid)
-        req = InferRequest(rid, tenant, inputs, self._round,
-                           priority=priority, deadline_s=deadline_s,
-                           submit_s=submit_s,
-                           depth_at_submit=len(self.queues[tenant]),
-                           seq_len=seq_len, bucket=bucket,
-                           deadline_abs_override_s=deadline_abs_s)
-        if not self.queues[tenant]:
-            self._head_since[tenant] = self._steps
-        self.queues[tenant].append(req)
-        if self.compiler is not None and self.compiler.prefetch:
-            # announce the bucket transition at ARRIVAL: the lattice
-            # point the next round will dispatch at (current heads'
-            # buckets) goes straight into the prefetch queue, so a
-            # prefill->decode transition compiles off-path before it is
-            # ever demanded — the lattice walk alone only reaches one
-            # rung per observed round and a decode bucket can be several
-            # rungs down.  Fires on ANY arrival while a bucketed head is
-            # queued (an unbucketed tenant joining changes the lattice
-            # point too); pure fixed-shape traffic never reaches it.
-            active = [t for t, q in enumerate(self.queues) if q]
-            shapes = {t: self.queues[t][0].bucket for t in active
-                      if self.queues[t][0].bucket is not None}
-            if shapes:
-                self.compiler.submit(
-                    self.session.plan_key(active, shapes),
-                    source="prefetch", priority=0.25)
-        return rid
+        with span(SUBMIT, tenant=tenant) as sp:
+            priority = Priority(priority)
+            bucket = self._resolve_bucket(tenant, seq_len)
+            if arrival_s is not None:
+                self.advance_clock(arrival_s)
+            submit_s = arrival_s if arrival_s is not None else self.clock_s
+            self.class_submitted[priority] += 1
+            rid = self._next_rid
+            self._next_rid += 1
+            sp.set_metadata(rid=rid)
+            if (self.admission is not None
+                    and not self.admission.admit(priority,
+                                                 self._class_depths())):
+                # rejected before any input generation; no arrays retained
+                self.rejected.append(
+                    InferRequest(rid, tenant, None, self._round,
+                                 priority=priority, deadline_s=deadline_s,
+                                 submit_s=submit_s,
+                                 depth_at_submit=len(self.queues[tenant]),
+                                 seq_len=seq_len, bucket=bucket,
+                                 deadline_abs_override_s=deadline_abs_s))
+                return None
+            if (priority != Priority.NORMAL or deadline_s is not None
+                    or deadline_abs_s is not None):
+                # only ADMITTED SLO traffic ends the zero-cost FIFO
+                # short-circuit — a rejected request never enters a queue
+                self._slo_seen = True
+            if bucket is not None:
+                # price the request's floor before it can be dispatched
+                # (and never inside a round): compile-alone at the bucket
+                self.session.bucket_single(tenant, bucket)
+            if inputs is None and self.execute:
+                from repro.core.runtime import init_inputs
+                g = (self.session.bucket_graph(tenant, bucket)
+                     if bucket is not None else self.compiled.graphs[tenant])
+                inputs = init_inputs(g, seed + rid)
+            req = InferRequest(rid, tenant, inputs, self._round,
+                               priority=priority, deadline_s=deadline_s,
+                               submit_s=submit_s,
+                               depth_at_submit=len(self.queues[tenant]),
+                               seq_len=seq_len, bucket=bucket,
+                               deadline_abs_override_s=deadline_abs_s)
+            if not self.queues[tenant]:
+                self._head_since[tenant] = self._steps
+            self.queues[tenant].append(req)
+            if self.compiler is not None and self.compiler.prefetch:
+                # announce the bucket transition at ARRIVAL: the lattice
+                # point the next round will dispatch at (current heads'
+                # buckets) goes straight into the prefetch queue, so a
+                # prefill->decode transition compiles off-path before it
+                # is ever demanded — the lattice walk alone only reaches
+                # one rung per observed round and a decode bucket can be
+                # several rungs down.  Fires on ANY arrival while a
+                # bucketed head is queued (an unbucketed tenant joining
+                # changes the lattice point too); pure fixed-shape traffic
+                # never reaches it.
+                active = [t for t, q in enumerate(self.queues) if q]
+                shapes = {t: self.queues[t][0].bucket for t in active
+                          if self.queues[t][0].bucket is not None}
+                if shapes:
+                    self.compiler.submit(
+                        self.session.plan_key(active, shapes),
+                        source="prefetch", priority=0.25)
+            return rid
 
     @property
     def pending(self) -> int:
@@ -424,17 +429,26 @@ class MultiModelEngine:
         """The round's occupancy plan at the given bucket vector, or
         ``None`` for a floor/fallback round.  With a background compiler
         attached the lookup never compiles: a miss enqueues the compile
-        and this round serves the compile-alone concat floor."""
-        if self.compiler is not None:
-            # every dispatched lattice point (hit or miss) anchors the
-            # compiler's shape/occupancy-lattice prefetcher
-            key = self.session.plan_key(ids, shapes)
-            self.compiler.observe(key)
-            plan = self.session.try_plan_for(key, touch=True)
-            if plan is None:
-                self.compiler.submit(key)
-            return plan, plan is None          # floor round on miss
-        return self.compiled.plan_for(ids, shapes=shapes), False
+        and this round serves the compile-alone concat floor.  The
+        ``repro.plan`` span's ``hit`` is false when the store did not hold
+        the plan."""
+        with span(PLAN) as sp:
+            if self.compiler is not None:
+                # every dispatched lattice point (hit or miss) anchors the
+                # compiler's shape/occupancy-lattice prefetcher
+                key = self.session.plan_key(ids, shapes)
+                self.compiler.observe(key)
+                plan = self.session.try_plan_for(key, touch=True)
+                if plan is None:
+                    self.compiler.submit(key)
+                sp.set_metadata(hit=plan is not None)
+                return plan, plan is None          # floor round on miss
+            store = getattr(self.session, "store", None)
+            misses = store.misses if store is not None else 0
+            plan = self.compiled.plan_for(ids, shapes=shapes)
+            sp.set_metadata(hit=plan is not None and (
+                store is None or store.misses == misses))
+            return plan, False
 
     def _param_dma_in_cycles(self, plan) -> float:
         """DMA cycles this plan spends loading parameter tensors — the
@@ -579,10 +593,12 @@ class MultiModelEngine:
             # positions in the occupancy plan follow sorted tenant ids,
             # which is the order ``ids`` arrives in
             reqs = [self._pop_head(i) for i in ids]
-            outs = (execute_multi_plan(plan, [r.inputs for r in reqs],
-                                       [self.params[r.tenant]
-                                        for r in reqs])
-                    if self.execute else [None] * len(reqs))
+            outs = [None] * len(reqs)
+            if self.execute:
+                with span(EXECUTE, requests=len(reqs)):
+                    outs = execute_multi_plan(
+                        plan, [r.inputs for r in reqs],
+                        [self.params[r.tenant] for r in reqs])
             if len(reqs) == 1:
                 self.solo_dispatches += 1
                 self.solo_rounds += 1
@@ -626,8 +642,10 @@ class MultiModelEngine:
                          else self.compiled.singles[i].plan)
             else:
                 splan = self.compiled.tenant_plan(i)
-            out = (execute_plan(splan, r.inputs, self.params[i])
-                   if self.execute else None)
+            out = None
+            if self.execute:
+                with span(EXECUTE, requests=1):
+                    out = execute_plan(splan, r.inputs, self.params[i])
             self.solo_dispatches += 1
             self.busy_cycles += splan.makespan
             round_offset += splan.makespan
@@ -653,19 +671,27 @@ class MultiModelEngine:
         active = [i for i, q in enumerate(self.queues) if q]
         if not active:
             return []
-        ids = sorted(self._compose_round(active))
-        completed: List[int] = []
-        budget = {i: min(len(self.queues[i]), self.max_batch) for i in ids}
-        prev_plan = None
-        while True:
-            wave = [i for i in ids if budget[i] > 0 and self.queues[i]]
-            if not wave:
-                break
-            prev_plan = self._dispatch_wave(wave, completed, prev_plan)
-            for i in wave:
-                budget[i] -= 1
-        self._steps += 1
-        return completed
+        with span(STEP, active=joined(active)):
+            ids = sorted(self._compose_round(active))
+            completed: List[int] = []
+            budget = {i: min(len(self.queues[i]), self.max_batch)
+                      for i in ids}
+            prev_plan = None
+            while True:
+                wave = [i for i in ids if budget[i] > 0 and self.queues[i]]
+                if not wave:
+                    break
+                first, start_s = len(completed), self.clock_s
+                with span(WAVE, occupancy=len(wave)) as sp:
+                    prev_plan = self._dispatch_wave(wave, completed,
+                                                    prev_plan)
+                    sp.set_metadata(
+                        rids=joined(completed[first:]),
+                        analytic_us=1e6 * (self.clock_s - start_s))
+                for i in wave:
+                    budget[i] -= 1
+            self._steps += 1
+            return completed
 
     def run(self) -> Dict[int, Dict[str, Any]]:
         """Drain all queues; returns {rid: output arrays}."""

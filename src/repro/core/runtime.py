@@ -9,6 +9,12 @@ Two executors:
   segments are stitched back into the full tensors, mirroring what the
   generated multi-device binary does on the SoC.
 
+A plan's structure (kernel order, tile ranges, slices, pads, stitch
+offsets) is Python fixed when the plan is compiled, so the tile-stitching
+executor is traced once per plan into one jitted XLA program whose
+arguments are the inputs and the weights (:class:`PlanPrograms`): a plan
+execution is one dispatch.  :func:`execute_graph` stays eager.
+
 ``execute_plan(plan) ≈ execute_graph(graph)`` (allclose) is the correctness
 contract of the whole compiler and is asserted by the tests for every
 benchmark model and every toolchain mode.
@@ -25,8 +31,12 @@ with per-dtype tolerances.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+import re
+import threading
+import weakref
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +45,8 @@ from jax import lax
 
 from repro.core.ir import Graph, Op, tile_axis
 from repro.core.rewrite import Supernode, TiledGraph
-from repro.core.schedule import ExecutionPlan, PlanNode
-from repro.core.spans import KERNEL, span
+from repro.core.schedule import ExecutionPlan
+from repro.core.spans import KERNEL
 
 Arrays = Dict[str, jnp.ndarray]
 
@@ -356,47 +366,125 @@ class _TenantExecutor:
         return {t: self.env[t] for t in self.g.outputs}
 
 
-def _kernel_span(ex: _TenantExecutor, n: PlanNode):
-    """The ``repro.kernel`` span of plan node ``n``, run by ``ex``."""
-    return span(KERNEL, tenant=ex.g.name, supernode=n.supernode,
-                resource=n.resource, analytic_cycles=n.duration)
+def kernel_scope(tenant: str, supernode: str) -> str:
+    """Name of the ``jax.named_scope`` that holds one kernel node's ops,
+    in the op metadata (``op_name``) of the device ops it compiles to.
+    Characters other than letters, digits, ``_``, ``.`` and ``-`` are
+    written ``_``: XLA's metadata cuts a scope at an ``@``, which host
+    supernodes (``..._wildcard@host``) carry."""
+    return ":".join([KERNEL] + [re.sub(r"[^\w.\-]", "_", part)
+                                for part in (tenant, supernode)])
+
+
+def _plan_program(programs: "PlanPrograms", tenants: Sequence[TiledGraph],
+                  kernels: Sequence[Tuple[int, str]]) -> Callable:
+    """The jitted program of one plan: its tenants' graphs and its kernel
+    nodes as ``(tenant position, supernode)`` in scheduled order, closed
+    over as static Python.  Inputs and weights are the arguments."""
+
+    def plan_program(inputs_list: List[Arrays], params_list: List[Arrays]
+                     ) -> List[Arrays]:
+        programs._count_build()          # runs only while tracing
+        execs = [_TenantExecutor(tg, inputs_list[i], params_list[i])
+                 for i, tg in enumerate(tenants)]
+        for t, supernode in kernels:
+            ex = execs[t]
+            with jax.named_scope(kernel_scope(ex.g.name, supernode)):
+                ex.run_kernel(supernode)
+        return [ex.outputs() for ex in execs]
+
+    return jax.jit(plan_program)
+
+
+class PlanPrograms:
+    """One jitted program per plan, built on the plan's first execution
+    and reused after.
+
+    Keyed by ``id(plan)`` beside a weak reference to the plan (plans
+    compare by value and are unhashable): the program is dropped when its
+    plan is collected, as after a ``PlanStore`` eviction.  The program
+    closes over the plan's tenants and kernel list, never the plan.
+    ``programs_built`` counts traces (each followed by an XLA compile or
+    a persistent-cache load), ``program_calls`` plan executions."""
+
+    def __init__(self) -> None:
+        # re-entrant: a weak-reference callback may run while held
+        self._lock = threading.RLock()
+        self._by_id: Dict[int, Tuple[weakref.ref, Callable]] = {}
+        self.programs_built = 0
+        self.program_calls = 0
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._by_id)
+
+    def program(self, plan) -> Callable:
+        """The jitted program of ``plan`` (an :class:`ExecutionPlan` or a
+        :class:`~repro.core.schedule.MultiExecutionPlan`), taking
+        ``(inputs_list, params_list)``, one entry per tenant."""
+        key = id(plan)
+        with self._lock:
+            entry = self._by_id.get(key)
+            if entry is not None and entry[0]() is plan:
+                return entry[1]
+            single = isinstance(plan, ExecutionPlan)
+            tenants = [plan.tiled] if single else list(plan.tenants)
+            kernels = []
+            for name in plan.order:
+                n = plan.nodes[name]
+                if n.kind == "kernel" and n.supernode is not None:
+                    kernels.append((0 if single else n.tenant, n.supernode))
+            prog = _plan_program(self, tenants, kernels)
+            ref = weakref.ref(plan, functools.partial(self._drop, key))
+            self._by_id[key] = (ref, prog)
+            return prog
+
+    def run(self, plan, inputs_list: Sequence[Arrays],
+            params_list: Sequence[Arrays]) -> List[Arrays]:
+        prog = self.program(plan)
+        with self._lock:
+            self.program_calls += 1
+        return prog(list(inputs_list), list(params_list))
+
+    def _count_build(self) -> None:
+        with self._lock:
+            self.programs_built += 1
+
+    def _drop(self, key: int, ref: weakref.ref) -> None:
+        with self._lock:
+            entry = self._by_id.get(key)
+            if entry is not None and entry[0] is ref:
+                del self._by_id[key]
+
+
+# the process's plan programs, which ``execute_plan`` and
+# ``execute_multi_plan`` run
+programs = PlanPrograms()
 
 
 def execute_plan(plan: ExecutionPlan, inputs: Arrays, params: Arrays
                  ) -> Arrays:
-    """Tile-by-tile execution following the compiled plan.
+    """Tile-by-tile execution following the compiled plan, as the plan's
+    one jitted program.
 
     Segments are stitched with ``dynamic_update_slice`` (the concat helper);
     supernodes run in the plan's scheduled order, which respects data
     dependencies by construction (validated by ``validate_schedule``)."""
-    ex = _TenantExecutor(plan.tiled, inputs, params)
-    for node_name in plan.order:
-        n = plan.nodes[node_name]
-        if n.kind == "kernel" and n.supernode is not None:
-            with _kernel_span(ex, n):
-                ex.run_kernel(n.supernode)
-    return ex.outputs()
+    return programs.run(plan, [inputs], [params])[0]
 
 
 def execute_multi_plan(plan, inputs_list: Sequence[Arrays],
                        params_list: Sequence[Arrays]) -> List[Arrays]:
     """Interleaved-tenant execution of a
-    :class:`repro.core.schedule.MultiExecutionPlan`.
+    :class:`repro.core.schedule.MultiExecutionPlan`, as the plan's one
+    jitted program.
 
     Kernels run in global scheduled order; each dispatches into its
     tenant's private executor, so N models make progress concurrently the
     way the co-schedule interleaves them on the SoC.  Numerics are
     identical to running each model alone (asserted by
     :func:`multi_plan_matches_oracle`)."""
-    execs = [_TenantExecutor(tg, inputs_list[i], params_list[i])
-             for i, tg in enumerate(plan.tenants)]
-    for node_name in plan.order:
-        n = plan.nodes[node_name]
-        if n.kind == "kernel" and n.supernode is not None:
-            ex = execs[n.tenant]
-            with _kernel_span(ex, n):
-                ex.run_kernel(n.supernode)
-    return [ex.outputs() for ex in execs]
+    return programs.run(plan, inputs_list, params_list)
 
 
 def plan_matches_oracle(plan: ExecutionPlan, seed: int = 0,

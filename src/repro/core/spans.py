@@ -13,18 +13,24 @@ planes by name, and their arguments among the event's stats.
   (the requests it served), ``occupancy``, ``analytic_us``.
 * ``repro.plan`` — the wave's plan lookup (``_resolve_plan``); ``hit``.
 * ``repro.execute`` — the executor call of a wave (one per
-  ``execute_plan`` on a floor round); ``requests``.
-* ``repro.kernel`` — each kernel node run by ``execute_plan`` /
-  ``execute_multi_plan``; ``tenant``, ``supernode``, ``resource`` (the
-  SoC unit the plan put it on), ``analytic_cycles``.
+  ``execute_plan`` on a floor round), one dispatch of the plan's jitted
+  program; ``requests``, ``built`` (true when this call traced and
+  compiled, or loaded, that program: once per plan).
 
-They nest ``step ⊃ wave ⊃ {plan, execute ⊃ kernel}`` on the thread that
-steps the engine.  A list of ids (``rids``, ``active``) is joined with
-:data:`SEP`: the profiler's argument encoding takes ``,``, ``=`` and
-``#`` for its own.  ``analytic_us`` is the wave's cost at the SoC clock
-and ``analytic_cycles`` the kernel node's planned duration: the schedule
-model's prediction beside the measured span.  ``tenant`` of a kernel is
-its model's graph name, the same in every plan the model runs in.
+They nest ``step ⊃ wave ⊃ {plan, execute}`` on the thread that steps the
+engine.  A list of ids (``rids``, ``active``) is joined with :data:`SEP`:
+the profiler's argument encoding takes ``,``, ``=`` and ``#`` for its
+own.  ``analytic_us`` is the wave's cost at the SoC clock: the schedule
+model's prediction beside the measured span.
+
+``repro.kernel`` is no host span: a plan runs as one jitted program
+(``repro.core.runtime.PlanPrograms``), so each kernel node's ops sit in a
+``jax.named_scope`` named ``repro.kernel:<tenant>:<supernode>`` inside
+it (``runtime.kernel_scope``), ``tenant`` being the model's graph name
+(the same in every plan the model runs in).  The scope is in the op
+metadata (``op_name``) of the compiled program's instructions, where a
+trace reader joins it to the device ops by instruction name; a fusion
+carries the scope of one of the ops it fused.
 """
 
 from __future__ import annotations

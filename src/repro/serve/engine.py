@@ -583,7 +583,7 @@ class MultiModelEngine:
                        prev_plan):
         """One serving round over exactly the tenants in ``ids``; returns
         the plan executed (for the batched repeat discount)."""
-        from repro.core.runtime import execute_multi_plan, execute_plan
+        from repro.core import runtime
         self._round += 1
         round_start = self.clock_s
         # the bucket vector of the heads this wave pops — resolved BEFORE
@@ -595,10 +595,13 @@ class MultiModelEngine:
             reqs = [self._pop_head(i) for i in ids]
             outs = [None] * len(reqs)
             if self.execute:
-                with span(EXECUTE, requests=len(reqs)):
-                    outs = execute_multi_plan(
+                with span(EXECUTE, requests=len(reqs)) as sp:
+                    built = runtime.programs.programs_built
+                    outs = runtime.execute_multi_plan(
                         plan, [r.inputs for r in reqs],
                         [self.params[r.tenant] for r in reqs])
+                    sp.set_metadata(
+                        built=runtime.programs.programs_built > built)
             if len(reqs) == 1:
                 self.solo_dispatches += 1
                 self.solo_rounds += 1
@@ -644,8 +647,12 @@ class MultiModelEngine:
                 splan = self.compiled.tenant_plan(i)
             out = None
             if self.execute:
-                with span(EXECUTE, requests=1):
-                    out = execute_plan(splan, r.inputs, self.params[i])
+                with span(EXECUTE, requests=1) as sp:
+                    built = runtime.programs.programs_built
+                    out = runtime.execute_plan(splan, r.inputs,
+                                               self.params[i])
+                    sp.set_metadata(
+                        built=runtime.programs.programs_built > built)
             self.solo_dispatches += 1
             self.busy_cycles += splan.makespan
             round_offset += splan.makespan

@@ -3,9 +3,12 @@
 A small ``two_acc_soc`` deployment is served through
 ``MultiModelEngine(execute=True)`` under ``jax.profiler.start_trace``,
 and the ``.xplane.pb`` is read back with ``ProfileData``: every span is
-there, nested ``step ⊃ wave ⊃ {plan, execute ⊃ kernel}`` on one host
-line, its arguments tie it to its request and its plan, and a running
-profiler changes neither the answers nor the engine's counters.
+there, nested ``step ⊃ wave ⊃ {plan, execute}`` on one host line, its
+arguments tie it to its request and its plan, and a running profiler
+changes neither the answers nor the engine's counters.  A plan runs as
+one jitted program, so ``repro.kernel`` is a named scope inside it and no
+host span (``tests/test_plan_program.py``); ``repro.execute`` says which
+call built its plan's program.
 """
 
 import glob
@@ -22,10 +25,9 @@ from repro.serve.compiler_thread import BackgroundCompiler
 from repro.serve.engine import MultiModelEngine
 from repro.soc.testbed import dense_chain, two_acc_soc
 
-NAMES = {spans.SUBMIT, spans.STEP, spans.WAVE, spans.PLAN, spans.EXECUTE,
-         spans.KERNEL}
+NAMES = {spans.SUBMIT, spans.STEP, spans.WAVE, spans.PLAN, spans.EXECUTE}
 PARENT = {spans.WAVE: spans.STEP, spans.PLAN: spans.WAVE,
-          spans.EXECUTE: spans.WAVE, spans.KERNEL: spans.EXECUTE}
+          spans.EXECUTE: spans.WAVE}
 COUNTERS = ("rounds", "co_rounds", "subset_co_rounds", "solo_rounds",
             "floor_rounds", "fallback_rounds", "batched_repeat_rounds",
             "solo_dispatches", "busy_cycles", "clock_s")
@@ -41,11 +43,6 @@ def make_session() -> DeploymentSession:
         requested_tiles=4, time_budget_s=0.05))
     s.compile()
     return s
-
-
-def kernel_nodes(plan) -> int:
-    return sum(1 for n in plan.nodes.values()
-               if n.kind == "kernel" and n.supernode is not None)
 
 
 def serve(session, bg=None):
@@ -115,12 +112,12 @@ def test_spans_nest_and_tie_to_requests_and_plans(tmp_path, monkeypatch,
     ``repro.plan`` span reads ``hit`` false."""
     session = make_session()
     assert session.try_plan_for([0, 1]) is None
-    ran = []
+    ran = []                            # the plan of each executor call
     for name in ("execute_plan", "execute_multi_plan"):
         inner = getattr(runtime, name)
 
         def record(plan, *args, _inner=inner):
-            ran.append(kernel_nodes(plan))
+            ran.append(plan)
             return _inner(plan, *args)
         monkeypatch.setattr(runtime, name, record)
     bg = BackgroundCompiler(session, start=False) if background else None
@@ -156,12 +153,14 @@ def test_spans_nest_and_tie_to_requests_and_plans(tmp_path, monkeypatch,
     assert sum(ev[3]["requests"] for ev in by[spans.EXECUTE]) == len(
         eng.done)
 
-    assert len(by[spans.KERNEL]) == sum(ran)
-    names = {g.name for g in session.compile().graphs}
-    for _, _, _, st in by[spans.KERNEL]:
-        assert st["tenant"] in names
-        assert st["supernode"] and st["resource"]
-        assert st["analytic_cycles"] > 0
+    # each call builds its plan's program the first time that plan runs
+    assert len(by[spans.EXECUTE]) == len(ran)
+    firsts, seen = [], set()
+    for plan in ran:
+        firsts.append(id(plan) not in seen)
+        seen.add(id(plan))
+    assert [bool(ev[3]["built"]) for ev in by[spans.EXECUTE]] == firsts
+    assert firsts.count(True) < len(firsts)     # some call reused one
 
 
 def test_a_running_profiler_changes_no_answer_and_no_counter(tmp_path):

@@ -51,22 +51,40 @@ class TensorInfo:
 # ---------------------------------------------------------------------------
 
 # Op types understood by the pipeline.  "ew_*" are elementwise.
+#
+# Sequence-model ops, on activations of shape (batch, seq, channels):
+#   * ``causal_conv1d`` -- depthwise conv along the sequence with zeros
+#     before the first token: inputs x (b, s, c), weight (k, c), bias (c,);
+#     ``y[t, c] = b[c] + sum_j w[j, c] * x[t - k + 1 + j, c]``;
+#   * ``ssd`` -- Mamba-2's selective state-space scan: inputs x (b, s, H*P),
+#     dt_raw (b, s, H), B and C (b, s, N) (one group), and the params
+#     A_log, dt_bias and D (H,); per head
+#     ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D x_t``
+#     with ``dt = softplus(dt_raw + dt_bias)`` and ``A = -exp(A_log)``,
+#     computed in chunks of attr ``chunk`` tokens;
+#   * ``silu`` -- x * sigmoid(x).
+# Attributes that leave the op unchanged when absent: ``softmax`` takes
+# ``causal`` (row r of the last two axes sees the columns c <= r mod
+# columns, so query rows of several heads may be stacked over one key
+# axis), ``mul`` with one input takes the scalar ``scale``, and
+# ``rmsnorm`` takes ``eps`` (default 1e-6).
 OP_TYPES = (
     "conv2d", "dwconv2d", "dense", "matmul", "batch_matmul",
     "add", "mul", "sub", "bias_add",
-    "relu", "relu6", "gelu", "sigmoid", "tanh", "erf", "softmax",
-    "layernorm", "rmsnorm",
+    "relu", "relu6", "gelu", "sigmoid", "tanh", "erf", "silu", "softmax",
+    "layernorm", "rmsnorm", "causal_conv1d", "ssd",
     "avg_pool2d", "max_pool2d", "global_avg_pool",
     "reshape", "flatten", "transpose", "slice", "concat", "pad", "identity",
 )
 
 _ELEMENTWISE = {"add", "mul", "sub", "bias_add", "relu", "relu6", "gelu",
-                "sigmoid", "tanh", "erf", "identity"}
+                "sigmoid", "tanh", "erf", "silu", "identity"}
 # Approximate arithmetic ops per element for non-MAC operators.
 _EW_OPS_PER_ELEM = {
     "add": 1.0, "mul": 1.0, "sub": 1.0, "bias_add": 1.0, "relu": 1.0,
     "relu6": 2.0, "gelu": 8.0, "sigmoid": 4.0, "tanh": 4.0, "erf": 8.0,
-    "identity": 0.0, "softmax": 5.0, "layernorm": 8.0, "rmsnorm": 6.0,
+    "silu": 5.0, "identity": 0.0, "softmax": 5.0, "layernorm": 8.0,
+    "rmsnorm": 6.0,
 }
 
 
@@ -98,6 +116,9 @@ class Graph:
         self._order: List[str] = []          # insertion order == topo order
         self.inputs: List[str] = []
         self.outputs: List[str] = []
+        # tokens a request carries: a language-model stage's sequence
+        # length, 0 for a model that takes no tokens
+        self.tokens = 0
 
     # -- construction -------------------------------------------------------
     def add_input(self, name: str, shape: Sequence[int],
@@ -218,7 +239,8 @@ def infer_shape(g: Graph, op: Op) -> Tuple[Tuple[int, ...], str]:
         *lead, m, _ = t[0].shape
         nn = t[1].shape[-1]
         return (*lead, m, nn), dtype
-    if ot in _ELEMENTWISE or ot in ("softmax", "layernorm", "rmsnorm", "identity"):
+    if ot in _ELEMENTWISE or ot in ("softmax", "layernorm", "rmsnorm",
+                                    "identity", "causal_conv1d", "ssd"):
         return t[0].shape, dtype
     if ot in ("avg_pool2d", "max_pool2d"):
         n, h, w, c = t[0].shape
@@ -280,9 +302,33 @@ def op_macs(g: Graph, op: Op) -> int:
     if op.op_type in ("matmul", "batch_matmul"):
         k = g.tensors[op.inputs[0]].shape[-1]
         return out.elements * k
-    if op.op_type in ("avg_pool2d", "max_pool2d"):
-        return 0
+    if op.op_type == "causal_conv1d":
+        return out.elements * g.tensors[op.inputs[1]].shape[0]
+    if op.op_type == "ssd":
+        b, s, hp = out.shape
+        heads = g.tensors[op.inputs[4]].shape[0]
+        state = g.tensors[op.inputs[2]].shape[-1]
+        return b * ssd_macs(s, heads, hp // heads, state, op.attrs["chunk"])
     return 0
+
+
+def ssd_chunk(seq: int, chunk: int) -> int:
+    """Chunk length of an ``ssd`` over ``seq`` tokens: ``chunk``, or the
+    whole sequence when it is shorter."""
+    return min(chunk, seq)
+
+
+def ssd_macs(seq: int, heads: int, head_dim: int, state: int,
+             chunk: int) -> int:
+    """Multiply-accumulates of the chunked ``ssd`` of one sequence, the
+    sequence padded to whole chunks: per chunk of L tokens, C.B^T over the
+    one group (L*L*N), the masked intra-chunk product (H*L*L*P), the
+    chunk's final state and the states' read-out (2*H*L*P*N)."""
+    n = ssd_chunk(seq, chunk)
+    chunks = -(-seq // n)
+    per_chunk = n * n * state + heads * n * (n * head_dim
+                                             + 2 * head_dim * state)
+    return chunks * per_chunk
 
 
 def op_arith(g: Graph, op: Op) -> float:
@@ -303,9 +349,13 @@ def op_arith(g: Graph, op: Op) -> float:
 # Ops whose output can be partitioned into independent tiles (paper §3.1:
 # feature-map rows for convolutions, output neurons for dense layers).
 _ROW_TILED = {"conv2d", "dwconv2d", "add", "mul", "sub", "bias_add", "relu",
-              "relu6", "gelu", "sigmoid", "tanh", "erf", "avg_pool2d",
-              "max_pool2d", "layernorm", "rmsnorm", "softmax", "identity"}
+              "relu6", "gelu", "sigmoid", "tanh", "erf", "silu",
+              "avg_pool2d", "max_pool2d", "layernorm", "rmsnorm", "softmax",
+              "identity"}
 _NEURON_TILED = {"dense", "matmul", "batch_matmul"}
+# Sequence-model ops tiled on their output channels: a causal conv's
+# channels are independent, and an ssd's heads are (``tile_extent``).
+_CHANNEL_TILED = {"causal_conv1d", "ssd"}
 
 
 def tile_axis(g: Graph, op: Op) -> Optional[int]:
@@ -316,7 +366,7 @@ def tile_axis(g: Graph, op: Op) -> Optional[int]:
     dense+bias_add+relu tile consistently (the executor computes one tile
     index range for the whole chain)."""
     out = g.tensors[op.output]
-    if op.op_type in _NEURON_TILED:
+    if op.op_type in _NEURON_TILED or op.op_type in _CHANNEL_TILED:
         return len(out.shape) - 1          # output neurons / columns
     if op.op_type in _ROW_TILED:
         if op.op_type in _ELEMENTWISE:
@@ -324,7 +374,7 @@ def tile_axis(g: Graph, op: Op) -> Optional[int]:
             for _ in range(4):
                 if p is None:
                     break
-                if p.op_type in _NEURON_TILED:
+                if p.op_type in _NEURON_TILED or p.op_type in _CHANNEL_TILED:
                     return len(out.shape) - 1
                 if p.op_type not in _ELEMENTWISE:
                     break
@@ -336,12 +386,20 @@ def tile_axis(g: Graph, op: Op) -> Optional[int]:
     return None                             # not tileable (reshape, concat, ...)
 
 
+def tile_extent(g: Graph, op: Op) -> int:
+    """Units the tile axis splits into: its length, or an ssd's heads
+    (a head's channels stay in one tile)."""
+    if op.op_type == "ssd":
+        return g.tensors[op.inputs[4]].shape[0]
+    return g.tensors[op.output].shape[tile_axis(g, op)]
+
+
 def max_tiles(g: Graph, op: Op, requested: int) -> int:
     """T_v: number of equal tiles; clamps to the extent of the tile axis."""
     ax = tile_axis(g, op)
     if ax is None:
         return 1
-    extent = g.tensors[op.output].shape[ax]
+    extent = tile_extent(g, op)
     t = min(requested, extent)
     # Equal tiles keep Eq. (2) linear; use the largest divisor <= requested.
     while extent % t != 0:

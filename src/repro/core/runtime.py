@@ -31,6 +31,7 @@ with per-dtype tolerances.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import re
@@ -43,10 +44,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core.ir import Graph, Op, tile_axis
+from repro.core.ir import Graph, Op, ssd_chunk, tile_axis
 from repro.core.rewrite import Supernode, TiledGraph
 from repro.core.schedule import ExecutionPlan
-from repro.core.spans import KERNEL
+from repro.core.spans import KERNEL, OP
 
 Arrays = Dict[str, jnp.ndarray]
 
@@ -116,7 +117,91 @@ def _matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(x, w, precision=PRECISION)
 
 
-def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
+def _einsum(spec: str, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jnp.einsum(spec, a, b, precision=PRECISION)
+
+
+def _softmax(x: jnp.ndarray, causal: bool, row0: int) -> jnp.ndarray:
+    """Softmax over the last axis; ``causal`` masks the columns above row
+    ``(row0 + r) mod columns`` of each row ``r`` of the last two axes
+    (``row0`` is a row tile's first row)."""
+    if causal:
+        rows, cols = x.shape[-2:]
+        pos = (row0 + jnp.arange(rows)) % cols
+        x = jnp.where(jnp.arange(cols)[None, :] <= pos[:, None], x, -jnp.inf)
+    return jax.nn.softmax(x, axis=-1)
+
+
+def _causal_conv1d(x: jnp.ndarray, w: jnp.ndarray,
+                   b: jnp.ndarray) -> jnp.ndarray:
+    """Depthwise conv of (batch, seq, channels) along the sequence, with
+    zeros before the first token: ``w`` is (taps, channels)."""
+    k, s = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = b
+    for j in range(k):
+        y = y + w[j] * xp[:, j:j + s]
+    return y
+
+
+def _segsum(a: jnp.ndarray) -> jnp.ndarray:
+    """(..., L) -> (..., L, L): entry [i, j] is ``sum(a[j+1 : i+1])`` for
+    j <= i and -inf above the diagonal.  Mamba-2's masked cumulative sum:
+    each entry sums its own segment's terms, never the difference of two
+    running sums, which loses the short segments' digits."""
+    n = a.shape[-1]
+    rep = jnp.broadcast_to(a[..., :, None], a.shape + (n,))
+    below = jnp.tril(jnp.ones((n, n), bool), -1)
+    seg = jnp.cumsum(jnp.where(below, rep, 0.0), axis=-2)
+    return jnp.where(jnp.tril(jnp.ones((n, n), bool)), seg, -jnp.inf)
+
+
+def _ssd(x, dt_raw, B, C, A_log, dt_bias, D, chunk: int) -> jnp.ndarray:
+    """The ``ssd`` op in chunked form: within a chunk, masked matmuls of
+    the decays; across chunks, each chunk's final state carried by a
+    ``lax.scan``.  The sequence is padded with zero tokens to whole
+    chunks (dt 0 there: no decay and no input) and cut back after."""
+    b, s, hp = x.shape
+    heads, state = A_log.shape[0], B.shape[-1]
+    p = hp // heads
+    n = ssd_chunk(s, chunk)
+    pad = -s % n
+    c = (s + pad) // n
+    dt = jax.nn.softplus(dt_raw + dt_bias)                  # (b, s, H)
+
+    def chunks(v):
+        v = jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+        return v.reshape(b, c, n, *v.shape[2:])
+
+    xdt = chunks(x.reshape(b, s, heads, p) * dt[..., None])  # (b,c,L,H,P)
+    Bc, Cc = chunks(B), chunks(C)                             # (b,c,L,N)
+    dA = jnp.moveaxis(chunks(dt * -jnp.exp(A_log)), 2, 3)     # (b,c,H,L)
+    seg = _segsum(dA)                                         # (b,c,H,L,L)
+    # outputs from the chunk's own tokens
+    cb = _einsum("bcln,bcsn->bcls", Cc, Bc)
+    y = _einsum("bchls,bcshp->bclhp", cb[:, :, None] * jnp.exp(seg), xdt)
+    # each chunk's final state, then the state entering every chunk
+    to_end = jnp.moveaxis(jnp.exp(seg[..., -1, :]), 2, 3)     # (b,c,L,H)
+    ends = _einsum("bcsn,bcshp->bchpn", Bc, xdt * to_end[..., None])
+    whole = jnp.exp(jnp.sum(dA, axis=-1))                     # (b,c,H)
+
+    def carry(h, chunk_end):
+        end, decay = chunk_end
+        return decay[..., None, None] * h + end, h
+
+    _, into = lax.scan(carry, jnp.zeros((b, heads, p, state), x.dtype),
+                       (jnp.moveaxis(ends, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    into = jnp.moveaxis(into, 0, 1)                           # (b,c,H,P,N)
+    from_start = jnp.moveaxis(jnp.exp(jnp.cumsum(dA, axis=-1)), 2, 3)
+    y = y + _einsum("bcln,bchpn->bclhp", Cc, into) * from_start[..., None]
+    y = y.reshape(b, c * n, hp)[:, :s]
+    return y + x * jnp.repeat(D, p)
+
+
+def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray],
+           row0: int = 0) -> jnp.ndarray:
+    """One op on whole (or tile-sliced) inputs; ``row0`` is the first row
+    of a row tile, which a causal softmax needs."""
     a = op.attrs
     ot = op.op_type
     if ot in ("conv2d", "dwconv2d"):
@@ -132,7 +217,7 @@ def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
     if ot == "sub":
         return ins[0] - ins[1]
     if ot == "mul":
-        return ins[0] * ins[1]
+        return ins[0] * (ins[1] if len(ins) > 1 else a["scale"])
     if ot == "bias_add":
         return ins[0] + ins[1]
     if ot == "relu":
@@ -147,8 +232,10 @@ def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
         return jnp.tanh(ins[0])
     if ot == "erf":
         return lax.erf(ins[0])
+    if ot == "silu":
+        return jax.nn.silu(ins[0])
     if ot == "softmax":
-        return jax.nn.softmax(ins[0], axis=-1)
+        return _softmax(ins[0], a.get("causal", False), row0)
     if ot == "layernorm":
         x = ins[0]
         mu = jnp.mean(x, axis=-1, keepdims=True)
@@ -159,7 +246,8 @@ def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
         return y
     if ot == "rmsnorm":
         x = ins[0]
-        y = x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
+        y = x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                         + a.get("eps", 1e-6))
         if len(ins) >= 2:
             y = y * ins[1]
         return y
@@ -176,6 +264,10 @@ def run_op(g: Graph, op: Op, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
         return summed / float(k * k)
     if ot == "global_avg_pool":
         return jnp.mean(ins[0], axis=(1, 2))
+    if ot == "causal_conv1d":
+        return _causal_conv1d(*ins)
+    if ot == "ssd":
+        return _ssd(*ins, chunk=a["chunk"])
     if ot == "reshape":
         return jnp.reshape(ins[0], tuple(g.tensors[op.output].shape))
     if ot == "flatten":
@@ -277,46 +369,79 @@ def run_supernode(g: Graph, sn: Supernode, env: Arrays) -> Dict[str, jnp.ndarray
                 ins_full.append(None)        # consumed as the running tile
             else:
                 ins_full.append(env[t])
-        if ax is None or full:
-            # untiled op (or the full-range segment): plain execution
-            ins = [prev_tile if v is None else v for v in ins_full]
-            tile = run_op(g, op, ins)
-        else:
-            c0, c1 = _coord_range(g, op, lo, hi, T, ax)
-            out_shape = g.tensors[op.output].shape
-            if op.op_type in ("conv2d", "dwconv2d", "avg_pool2d",
-                              "max_pool2d"):
-                assert prev_tile is None, "conv must head its chain"
-                tile = _conv_row_tile(g, op, ins_full, c0, c1)
-            elif op.op_type in ("dense", "matmul", "batch_matmul"):
-                assert prev_tile is None, "gemm must head its chain"
-                x, w = ins_full[0], ins_full[1]
-                tile = _matmul(x, _slice_axis(w, w.ndim - 1, c0, c1))
-            else:
-                # elementwise / normalization: slice every full input along
-                # the tile axis; 1-D bias broadcasts slice on the last axis
-                # only when that *is* the tile axis (neuron tiling).
-                ins = []
-                for v, t in zip(ins_full, op.inputs):
-                    if v is None:
-                        ins.append(prev_tile)
-                        continue
-                    ti = g.tensors[t]
-                    if len(ti.shape) == len(out_shape):
-                        if ti.shape[ax] == out_shape[ax]:
-                            ins.append(_slice_axis(v, ax, c0, c1))
-                        else:
-                            ins.append(v)            # broadcast dim
-                    elif (len(ti.shape) == 1
-                          and ax == len(out_shape) - 1
-                          and ti.shape[0] == out_shape[-1]):
-                        ins.append(v[c0:c1])         # sliced bias (neuron)
-                    else:
-                        ins.append(v)
-                tile = run_op(g, op, ins)
+        with op_scope(op):
+            tile = _run_tile(g, op, ins_full, prev_tile, ax,
+                             None if ax is None or full
+                             else _coord_range(g, op, lo, hi, T, ax))
         results[op.output] = tile
         prev_tile, prev_out = tile, op.output
     return results
+
+
+def op_scope(op: Op):
+    """A ``jax.named_scope`` ``repro.op:<op type>`` around the ops of a
+    sequence-model mechanism (``ssd``, ``causal_conv1d``, a causal
+    softmax) inside its kernel's scope, so that device time can be put
+    down to the mechanism; no scope for other ops."""
+    if op.op_type in ("ssd", "causal_conv1d") or (
+            op.op_type == "softmax" and op.attrs.get("causal")):
+        return jax.named_scope(f"{OP}:{op.op_type}")
+    return contextlib.nullcontext()
+
+
+def _run_tile(g: Graph, op: Op, ins_full: List[Optional[jnp.ndarray]],
+              prev_tile: Optional[jnp.ndarray], ax: Optional[int],
+              coords: Optional[Tuple[int, int]]) -> jnp.ndarray:
+    """One op of a supernode's chain over the tile ``coords`` of its tile
+    axis ``ax`` (None: the whole output); ``None`` in ``ins_full`` is the
+    chain's running tile ``prev_tile``."""
+    if coords is None:
+        # untiled op (or the full-range segment): plain execution
+        ins = [prev_tile if v is None else v for v in ins_full]
+        return run_op(g, op, ins)
+    c0, c1 = coords
+    out_shape = g.tensors[op.output].shape
+    if op.op_type in ("conv2d", "dwconv2d", "avg_pool2d", "max_pool2d"):
+        assert prev_tile is None, "conv must head its chain"
+        return _conv_row_tile(g, op, ins_full, c0, c1)
+    if op.op_type in ("dense", "matmul", "batch_matmul"):
+        assert prev_tile is None, "gemm must head its chain"
+        x, w = ins_full[0], ins_full[1]
+        return _matmul(x, _slice_axis(w, w.ndim - 1, c0, c1))
+    if op.op_type == "causal_conv1d":
+        assert prev_tile is None, "causal_conv1d must head its chain"
+        x, w, b = ins_full
+        return _causal_conv1d(x[..., c0:c1], w[:, c0:c1], b[c0:c1])
+    if op.op_type == "ssd":
+        # a tile of whole heads: their channels of x, their dt_raw,
+        # A_log, dt_bias and D; B and C are shared by every head
+        assert prev_tile is None, "ssd must head its chain"
+        x, dt_raw, B, C, A_log, dt_bias, D = ins_full
+        p = out_shape[-1] // A_log.shape[0]
+        h0, h1 = c0 // p, c1 // p
+        return _ssd(x[..., c0:c1], dt_raw[..., h0:h1], B, C, A_log[h0:h1],
+                    dt_bias[h0:h1], D[h0:h1], chunk=op.attrs["chunk"])
+    # elementwise / normalization: slice every full input along the tile
+    # axis; 1-D bias broadcasts slice on the last axis only when that *is*
+    # the tile axis (neuron tiling).
+    ins = []
+    for v, t in zip(ins_full, op.inputs):
+        if v is None:
+            ins.append(prev_tile)
+            continue
+        ti = g.tensors[t]
+        if len(ti.shape) == len(out_shape):
+            if ti.shape[ax] == out_shape[ax]:
+                ins.append(_slice_axis(v, ax, c0, c1))
+            else:
+                ins.append(v)            # broadcast dim
+        elif (len(ti.shape) == 1
+              and ax == len(out_shape) - 1
+              and ti.shape[0] == out_shape[-1]):
+            ins.append(v[c0:c1])         # sliced bias (neuron)
+        else:
+            ins.append(v)
+    return run_op(g, op, ins, row0=c0 if ax == len(out_shape) - 2 else 0)
 
 
 class _TenantExecutor:

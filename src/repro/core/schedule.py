@@ -90,9 +90,20 @@ class ExecutionPlan:
 
 def l3_resident(g: Graph, soc: SoC) -> Set[str]:
     """Tensors that never fit the L2 scratchpad: stay in L3, accessed via
-    planned loading (§3.2 strategy iii)."""
+    planned loading (§3.2 strategy iii).  That is every tensor above half
+    the scratchpad and, where an op's other operands together still pass
+    what the static parameters (strategy i) leave of it, their largest
+    until the rest fit: a kernel whose operands can never be in L2 at
+    once would block the scheduler for good."""
     cap = soc.l2.size // 2
-    return {t for t, ti in g.tensors.items() if ti.bytes > cap}
+    out = {t for t, ti in g.tensors.items() if ti.bytes > cap}
+    room = soc.l2.size * (1.0 - STATIC_PARAM_BUDGET)
+    for op in g.topo_ops():
+        rest = sorted({*op.inputs, op.output} - out,
+                      key=lambda t: (g.tensors[t].bytes, t))
+        while sum(g.tensors[t].bytes for t in rest) > room:
+            out.add(rest.pop())
+    return out
 
 
 STATIC_PARAM_BUDGET = 0.6      # fraction of L2 reserved for resident params

@@ -6,11 +6,14 @@ costs about a microsecond and a half when none is.  So the spans are
 always in place, with no switch.  A trace reader finds them on the host
 planes by name, and their arguments among the event's stats.
 
-* ``repro.submit`` — ``MultiModelEngine.submit``; ``rid``, ``tenant``.
+* ``repro.submit`` — ``MultiModelEngine.submit``; ``rid``, ``tenant``,
+  ``tokens`` (the request's tokens: a language-model stage's sequence
+  length, 0 for a model that takes none).
 * ``repro.step`` — ``MultiModelEngine.step``, round composition
   included; ``active`` (the tenants with queued work).
 * ``repro.wave`` — each wave of a step (``_dispatch_wave``); ``rids``
-  (the requests it served), ``occupancy``, ``analytic_us``.
+  (the requests it served), ``tokens`` (theirs, summed), ``occupancy``,
+  ``analytic_us``.
 * ``repro.plan`` — the wave's plan lookup (``_resolve_plan``); ``hit``.
 * ``repro.execute`` — the executor call of a wave (one per
   ``execute_plan`` on a floor round), one dispatch of the plan's jitted
@@ -30,7 +33,10 @@ it (``runtime.kernel_scope``), ``tenant`` being the model's graph name
 (the same in every plan the model runs in).  The scope is in the op
 metadata (``op_name``) of the compiled program's instructions, where a
 trace reader joins it to the device ops by instruction name; a fusion
-carries the scope of one of the ops it fused.
+carries the scope of one of the ops it fused.  Inside a kernel's scope
+the ops of a sequence-model mechanism sit in one more,
+``repro.op:<op type>`` (``ssd``, ``causal_conv1d``, ``softmax`` when
+causal; ``runtime.op_scope``).
 """
 
 from __future__ import annotations
@@ -45,6 +51,7 @@ WAVE = "repro.wave"
 PLAN = "repro.plan"
 EXECUTE = "repro.execute"
 KERNEL = "repro.kernel"
+OP = "repro.op"
 
 SEP = ";"
 

@@ -18,6 +18,10 @@ Microbenchmark blocks (Fig. 7):
   * ``resnet50_block``  — first bottleneck of ResNet-50 (1x1-3x3-1x1 + skip)
   * ``resnext50_block`` — first ResNeXt block, split-transform-merge branches
   * ``transformer_block`` — encoder layer, hidden 128, 4 heads, MHA+FFN+LN
+
+Language-model pipeline stages (``models/granite.py``):
+  * ``granite_h_micro_stage4`` — granite-4.0-h-micro's layers 30-39, final
+                       norm and head: a 2048-token prefill at published widths
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ from __future__ import annotations
 from typing import Tuple
 
 from repro.core.ir import Graph
+from repro.models.granite import LM_STAGES
 
 DT = "float16"
 
@@ -241,4 +246,4 @@ MLPERF_TINY = {"autoencoder": autoencoder, "ds_cnn": ds_cnn,
 BLOCKS = {"resnet50_block": resnet50_block,
           "resnext50_block": resnext50_block,
           "transformer_block": transformer_block}
-ALL_MODELS = {**MLPERF_TINY, **BLOCKS}
+ALL_MODELS = {**MLPERF_TINY, **BLOCKS, **LM_STAGES}

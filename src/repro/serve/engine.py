@@ -232,6 +232,12 @@ class MultiModelEngine:
                              f"artifacts only)")
         return spec.bucket_for(seq_len)
 
+    def _tokens(self, tenant: int, seq_len: Optional[int]) -> int:
+        """Tokens of one request: its ``seq_len`` when it gives one, else
+        the tenant graph's ``tokens`` (0 for a model that takes none)."""
+        return (seq_len if seq_len is not None
+                else self.compiled.graphs[tenant].tokens)
+
     def submit(self, model, inputs=None, seed: int = 0,
                priority: Priority = Priority.NORMAL,
                deadline_s: Optional[float] = None,
@@ -252,7 +258,8 @@ class MultiModelEngine:
         off the dispatch path.  Returns the request id, or ``None`` when
         admission rejected the request (recorded in ``rejected``)."""
         tenant = self.resolve(model)
-        with span(SUBMIT, tenant=tenant) as sp:
+        with span(SUBMIT, tenant=tenant,
+                  tokens=self._tokens(tenant, seq_len)) as sp:
             priority = Priority(priority)
             bucket = self._resolve_bucket(tenant, seq_len)
             if arrival_s is not None:
@@ -694,6 +701,9 @@ class MultiModelEngine:
                                                     prev_plan)
                     sp.set_metadata(
                         rids=joined(completed[first:]),
+                        tokens=sum(self._tokens(r.tenant, r.seq_len)
+                                   for r in map(self.done.get,
+                                                completed[first:])),
                         analytic_us=1e6 * (self.clock_s - start_s))
                 for i in wave:
                     budget[i] -= 1

@@ -63,15 +63,20 @@ def carfield_soc() -> SoC:
 
 # Per-device fused-pattern efficiencies.  Chains share the anchor op's eta
 # (fusing the cheap epilogue into the kernel is what the eta measures).
+# The sequence-model ops have no kernel measured on Carfield: a causal
+# conv takes a depthwise conv's eta, and an ssd (masked matmuls of short
+# chunks) a batch_matmul's -- assumed, not fitted.
 _PULP = {
     "conv2d": 0.65, "dwconv2d": 0.55, "dense": 0.33,
     "matmul": 0.33, "batch_matmul": 0.30,
     "add": 0.50, "avg_pool2d": 0.50, "max_pool2d": 0.50,
+    "causal_conv1d": 0.55, "ssd": 0.30,
 }
 _SPATZ = {
     "conv2d": 0.70, "dwconv2d": 0.10, "dense": 0.33,
     "matmul": 0.33, "batch_matmul": 0.30,
     "add": 0.50, "avg_pool2d": 0.40, "max_pool2d": 0.40,
+    "causal_conv1d": 0.10, "ssd": 0.30,
 }
 # host TVM kernels: per-op-type single patterns beat the generic wildcard
 _HOST = {
@@ -89,6 +94,8 @@ _EPILOGUES = {
     "add": [["relu"]],
     "avg_pool2d": [],
     "max_pool2d": [],
+    "causal_conv1d": [["silu"]],
+    "ssd": [],
 }
 
 D_ACC = 1500.0      # per-invocation overhead on an accelerator (cycles)

@@ -8,11 +8,14 @@ arguments tie it to its request and its plan, and a running profiler
 changes neither the answers nor the engine's counters.  A plan runs as
 one jitted program, so ``repro.kernel`` is a named scope inside it and no
 host span (``tests/test_plan_program.py``); ``repro.execute`` says which
-call built its plan's program.
+call built its plan's program.  A language-model stage puts its tokens on
+``repro.submit`` and ``repro.wave``, and its mechanisms' ops in
+``repro.op`` scopes inside their kernels'.
 """
 
 import glob
 import os
+import re
 
 import jax
 import numpy as np
@@ -23,6 +26,9 @@ from repro.core.deploy import CompileRequest, DeploymentSession
 from repro.core.runtime import init_inputs
 from repro.serve.compiler_thread import BackgroundCompiler
 from repro.serve.engine import MultiModelEngine
+from repro.models import edge
+from repro.models.granite import granite_h_micro_stage4
+from repro.soc.carfield import carfield_patterns, carfield_soc
 from repro.soc.testbed import dense_chain, two_acc_soc
 
 NAMES = {spans.SUBMIT, spans.STEP, spans.WAVE, spans.PLAN, spans.EXECUTE}
@@ -177,3 +183,51 @@ def test_a_running_profiler_changes_no_answer_and_no_counter(tmp_path):
         for t, v in out.items():
             assert np.array_equal(np.asarray(with_trace.results[rid][t]),
                                   np.asarray(v))
+
+
+def stage_session() -> DeploymentSession:
+    """A small granite stage (16 tokens) beside the autoencoder, which
+    takes no tokens, on the Carfield preset."""
+    stage = granite_h_micro_stage4(
+        seq=16, chunk=8, d=32, heads=2, head_dim=16, state=8, q_heads=2,
+        kv_heads=1, attn_head_dim=16, ffn=64, vocab=64, pattern="ma")
+    s = DeploymentSession(CompileRequest(
+        graphs=[stage, edge.autoencoder()], soc=carfield_soc(),
+        patterns=carfield_patterns(), time_budget_s=0.001,
+        joint_time_budget_s=0.001, lazy_joint_time_budget_s=0.001,
+        incremental_time_budget_s=0.001))
+    s.compile()
+    s.precompile([[0]])
+    return s
+
+
+def test_spans_carry_tokens_and_the_program_scopes_each_mechanism(tmp_path):
+    session = stage_session()
+    mc = session.compile()
+
+    def run():
+        eng = MultiModelEngine(mc, seed=3, execute=True, max_batch=1)
+        for occupancy in ([0, 1], [0]):
+            for t in occupancy:
+                eng.submit(t)
+            eng.step()
+        return eng
+
+    eng, lines = traced(tmp_path, run)
+    events = [ev for line in lines for ev in line]
+    submits = [ev[3] for ev in events if ev[0] == spans.SUBMIT]
+    assert [(s["tenant"], s["tokens"]) for s in submits] == [
+        (0, 16), (1, 0), (0, 16)]
+    waves = [ev[3] for ev in events if ev[0] == spans.WAVE]
+    assert [w["tokens"] for w in waves] == [16, 16]
+    assert [len(rids_of(w)) for w in waves] == [2, 1]
+
+    # each mechanism's ops sit in a scope of their own inside their
+    # kernel's, in the op metadata of the compiled program
+    plan = session.try_plan_for([0])
+    g = plan.tenants[0].graph
+    args = ([runtime.init_inputs(g, 1)], [runtime.init_params(g, 2)])
+    text = runtime.programs.program(plan).lower(*args).compile().as_text()
+    found = set(re.findall(r"repro\.kernel:[^/\"]*/(repro\.op:\w+)", text))
+    assert found == {f"{spans.OP}:{t}"
+                     for t in ("ssd", "causal_conv1d", "softmax")}

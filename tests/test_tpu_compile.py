@@ -8,7 +8,8 @@ lower, too much VMEM).  Interpret-mode tests cannot see any of that.
 The widths are those of ``repro.kernels.cases``, which the kernel phase
 of ``chip_smoke.py`` runs on the chip.  The plan program is that of a
 small ``two_acc_soc`` co-schedule: what the executor hands the chip's
-compiler for every plan it runs.
+compiler for every plan it runs; and that of a small granite stage, whose
+sequence-model ops have to compile for the chip too.
 """
 
 import os
@@ -22,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import runtime
 from repro.core.deploy import CompileRequest, DeploymentSession
 from repro.kernels.cases import CASES
+from repro.models.granite import granite_h_micro_stage4
+from repro.soc.carfield import carfield_patterns, carfield_soc
 from repro.soc.testbed import dense_chain, gelu_chain, two_acc_soc
 
 
@@ -90,3 +93,29 @@ def test_plan_program_compiles_for_v5e(one_chip, no_persistent_cache):
             if n.kind == "kernel" and n.supernode is not None}
     found = set(re.findall(r"repro\.kernel:[^/\"]*", text))
     assert found and found <= want, (found, want)
+
+
+def test_stage_plan_program_compiles_for_v5e(one_chip, no_persistent_cache):
+    """A small granite stage's plan on the Carfield preset: its ssd,
+    causal conv and causal softmax compile for the chip, and each sits in
+    its ``repro.op`` scope inside its kernel's."""
+    g = granite_h_micro_stage4(
+        seq=128, chunk=64, d=64, heads=2, head_dim=32, state=16,
+        q_heads=2, kv_heads=1, attn_head_dim=32, ffn=128, vocab=256,
+        pattern="ma")
+    session = DeploymentSession(CompileRequest(
+        graphs=[g], soc=carfield_soc(), patterns=carfield_patterns(),
+        time_budget_s=0.001, joint_time_budget_s=0.001,
+        lazy_joint_time_budget_s=0.001, incremental_time_budget_s=0.001))
+    plan = session.compile().plan
+
+    def shapes(names):
+        return {n: jax.ShapeDtypeStruct(g.tensors[n].shape, "float32",
+                                        sharding=one_chip) for n in names}
+
+    params = shapes([n for n, t in g.tensors.items() if t.kind == "param"])
+    text = runtime.programs.program(plan).lower(
+        [shapes(g.inputs)], [params]).compile().as_text()
+    found = set(re.findall(r"repro\.kernel:[^/\"]*/(repro\.op:\w+)", text))
+    assert found == {"repro.op:ssd", "repro.op:causal_conv1d",
+                     "repro.op:softmax"}

@@ -3,24 +3,29 @@
 Two executors:
 
 * :func:`execute_graph` — direct whole-graph evaluation (the oracle).
-* :func:`execute_plan` — tile-by-tile execution of an :class:`ExecutionPlan`:
-  every supernode computes exactly its tile segment of the fused chain
-  (including conv halos and the slice/concat helper semantics), and the
-  segments are stitched back into the full tensors, mirroring what the
-  generated multi-device binary does on the SoC.
+* :func:`execute_plan` — execution of an :class:`ExecutionPlan`: its
+  kernel nodes run in the plan's scheduled order, traced once per plan
+  into one jitted XLA program whose arguments are the inputs and the
+  weights (:class:`PlanPrograms`), so a plan execution is one dispatch.
+  The first kernel node that covers an op computes that op whole; the
+  op's later tile segments find its output computed (see
+  :class:`_TenantExecutor`).  :func:`execute_graph` stays eager.
 
-A plan's structure (kernel order, tile ranges, slices, pads, stitch
-offsets) is Python fixed when the plan is compiled, so the tile-stitching
-executor is traced once per plan into one jitted XLA program whose
-arguments are the inputs and the weights (:class:`PlanPrograms`): a plan
-execution is one dispatch.  :func:`execute_graph` stays eager.
+:func:`run_supernode` is the plan's tile semantics: what one kernel node
+computes of its fused chain over its tile segment (conv halos, the
+slice/concat helpers, a causal softmax's first row, whole-head ``ssd``
+tiles), as the generated multi-device binary does on the SoC.
+:func:`execute_plan_tiles` runs a plan that way, eagerly: every kernel
+node computes its segment and each op's segments are joined in tile
+order, which fails unless they tile the op exactly.
 
-``execute_plan(plan) ≈ execute_graph(graph)`` (allclose) is the correctness
-contract of the whole compiler and is asserted by the tests for every
-benchmark model and every toolchain mode.
+``execute_plan(plan) ≈ execute_graph(graph)`` and
+``execute_plan_tiles(plan) ≈ execute_graph(graph)`` (allclose) are the
+correctness contract of the whole compiler (:func:`plan_matches_oracle`),
+asserted by the tests for every benchmark model and every toolchain mode.
 
 Everything here runs in float32 regardless of the deployment dtype: the
-numerics validate *plan structure* (tiling, halos, segment stitching), not
+numerics validate *plan structure* (kernel order, tiling, halos), not
 reduced-precision kernels.  Every conv and matmul passes
 ``precision=PRECISION`` (``HIGHEST``): a TPU otherwise computes a float32
 conv or matmul from bfloat16 inputs, and the ``1e-4`` oracle tolerances
@@ -345,16 +350,11 @@ def _conv_row_tile(g: Graph, op: Op, ins: Sequence[jnp.ndarray],
     raise NotImplementedError(ot)
 
 
-def _chain_is_neuron_tiled(g: Graph, head: Op) -> bool:
-    ax = tile_axis(g, head)
-    out = g.tensors[head.output]
-    return ax is not None and ax == len(out.shape) - 1
-
-
 def run_supernode(g: Graph, sn: Supernode, env: Arrays) -> Dict[str, jnp.ndarray]:
     """Computes this supernode's tile segment for every op of its chain.
-    Returns {output tensor name: tile array} (to be stitched by the caller).
-    Reads full input tensors from ``env`` (slice helpers are applied here)."""
+    Returns {output tensor name: tile array}; an op's segments, joined in
+    tile order along its tile axis, make its whole output.  Reads full
+    input tensors from ``env`` (slice helpers are applied here)."""
     lo, hi, T = sn.tile_lo, sn.tile_hi, sn.T
     results: Dict[str, jnp.ndarray] = {}
     prev_tile: Optional[jnp.ndarray] = None
@@ -445,44 +445,38 @@ def _run_tile(g: Graph, op: Op, ins_full: List[Optional[jnp.ndarray]],
 
 
 class _TenantExecutor:
-    """Tile-stitching execution state for ONE model (tenant).
+    """Execution state for ONE model (tenant) inside its plan's program.
 
-    Runs supernode kernels in whatever order the schedule dictates and
-    stitches tile segments back into full tensors with
-    ``dynamic_update_slice`` (the concat-helper semantics).  Segments are
-    disjoint, so any interleaving with other tenants' kernels produces
-    bitwise-identical outputs to running this model alone."""
+    Runs kernel nodes in whatever order the schedule dictates.  The first
+    kernel node that covers an op computes the op whole, on its full
+    operands, as :func:`execute_graph` does; the op's later tile segments
+    find its output in ``env`` and compute nothing (they are *folded*).
+    The plan spreads an op's segments over the SoC's engines; inside one
+    jitted program on one device they would only add zero-filled buffers
+    to stitch into and a re-read of the op's inputs per segment, and XLA
+    orders the program by its dataflow, not by the trace.  An op's inputs
+    are whole in ``env`` by its first segment: every segment reads them
+    whole (:func:`run_supernode`).  Tenants share no tensors, so any
+    interleaving with other tenants' kernels gives each tenant the
+    outputs it gets alone."""
 
     def __init__(self, tg: TiledGraph, inputs: Arrays, params: Arrays
                  ) -> None:
         self.g = tg.graph
         self.env: Arrays = {**inputs, **params}
-        self.buf: Dict[str, jnp.ndarray] = {}
-        self.filled: Dict[str, int] = {}
         self.sn_by_name = {s.name: s for s in tg.supernodes}
 
-    def run_kernel(self, supernode: str) -> None:
+    def run_kernel(self, supernode: str) -> bool:
+        """Computes, whole, the ops of ``supernode``'s chain that no
+        earlier kernel node computed; False when there were none."""
         g = self.g
-        sn = self.sn_by_name[supernode]
-        tiles = run_supernode(g, sn, self.env)
-        for out_t, tile in tiles.items():
-            op = g.producer_of(out_t)
-            ax = tile_axis(g, op)
-            if ax is None or sn.full:
-                self.env[out_t] = tile
-                continue
-            if out_t not in self.buf:
-                self.buf[out_t] = jnp.zeros(g.tensors[out_t].shape,
-                                            dtype=tile.dtype)
-                self.filled[out_t] = 0
-            c0, _ = _coord_range(g, op, sn.tile_lo, sn.tile_hi, sn.T, ax)
-            start = [0] * self.buf[out_t].ndim
-            start[ax] = c0
-            self.buf[out_t] = lax.dynamic_update_slice(self.buf[out_t],
-                                                       tile, start)
-            self.filled[out_t] += sn.tiles
-            if self.filled[out_t] == sn.T:
-                self.env[out_t] = self.buf.pop(out_t)
+        todo = [g.ops[n] for n in self.sn_by_name[supernode].op_names
+                if g.ops[n].output not in self.env]
+        for op in todo:
+            with op_scope(op):
+                self.env[op.output] = run_op(
+                    g, op, [self.env[t] for t in op.inputs])
+        return bool(todo)
 
     def outputs(self) -> Arrays:
         missing = [t for t in self.g.outputs if t not in self.env]
@@ -501,6 +495,24 @@ def kernel_scope(tenant: str, supernode: str) -> str:
                                 for part in (tenant, supernode)])
 
 
+def plan_tenants(plan) -> List[TiledGraph]:
+    """The tiled graphs of ``plan`` (an :class:`ExecutionPlan` or a
+    :class:`~repro.core.schedule.MultiExecutionPlan`), one per tenant
+    position."""
+    if isinstance(plan, ExecutionPlan):
+        return [plan.tiled]
+    return list(plan.tenants)
+
+
+def plan_kernels(plan) -> List[Tuple[int, str]]:
+    """The kernel nodes of ``plan`` as ``(tenant position, supernode)``,
+    in scheduled order."""
+    single = isinstance(plan, ExecutionPlan)
+    nodes = [plan.nodes[name] for name in plan.order]
+    return [(0 if single else n.tenant, n.supernode) for n in nodes
+            if n.kind == "kernel" and n.supernode is not None]
+
+
 def _plan_program(programs: "PlanPrograms", tenants: Sequence[TiledGraph],
                   kernels: Sequence[Tuple[int, str]]) -> Callable:
     """The jitted program of one plan: its tenants' graphs and its kernel
@@ -509,13 +521,14 @@ def _plan_program(programs: "PlanPrograms", tenants: Sequence[TiledGraph],
 
     def plan_program(inputs_list: List[Arrays], params_list: List[Arrays]
                      ) -> List[Arrays]:
-        programs._count_build()          # runs only while tracing
         execs = [_TenantExecutor(tg, inputs_list[i], params_list[i])
                  for i, tg in enumerate(tenants)]
+        folded = 0
         for t, supernode in kernels:
             ex = execs[t]
             with jax.named_scope(kernel_scope(ex.g.name, supernode)):
-                ex.run_kernel(supernode)
+                folded += not ex.run_kernel(supernode)
+        programs._count_build(folded)    # runs only while tracing
         return [ex.outputs() for ex in execs]
 
     return jax.jit(plan_program)
@@ -530,7 +543,10 @@ class PlanPrograms:
     plan is collected, as after a ``PlanStore`` eviction.  The program
     closes over the plan's tenants and kernel list, never the plan.
     ``programs_built`` counts traces (each followed by an XLA compile or
-    a persistent-cache load), ``program_calls`` plan executions."""
+    a persistent-cache load), ``program_calls`` plan executions, and
+    ``segments_folded``, over those traces, the kernel nodes that
+    computed nothing: tile segments of ops an earlier segment computed
+    whole (:class:`_TenantExecutor`)."""
 
     def __init__(self) -> None:
         # re-entrant: a weak-reference callback may run while held
@@ -538,6 +554,7 @@ class PlanPrograms:
         self._by_id: Dict[int, Tuple[weakref.ref, Callable]] = {}
         self.programs_built = 0
         self.program_calls = 0
+        self.segments_folded = 0
 
     def __len__(self) -> int:
         with self._lock:
@@ -552,14 +569,8 @@ class PlanPrograms:
             entry = self._by_id.get(key)
             if entry is not None and entry[0]() is plan:
                 return entry[1]
-            single = isinstance(plan, ExecutionPlan)
-            tenants = [plan.tiled] if single else list(plan.tenants)
-            kernels = []
-            for name in plan.order:
-                n = plan.nodes[name]
-                if n.kind == "kernel" and n.supernode is not None:
-                    kernels.append((0 if single else n.tenant, n.supernode))
-            prog = _plan_program(self, tenants, kernels)
+            prog = _plan_program(self, plan_tenants(plan),
+                                 plan_kernels(plan))
             ref = weakref.ref(plan, functools.partial(self._drop, key))
             self._by_id[key] = (ref, prog)
             return prog
@@ -571,9 +582,10 @@ class PlanPrograms:
             self.program_calls += 1
         return prog(list(inputs_list), list(params_list))
 
-    def _count_build(self) -> None:
+    def _count_build(self, folded: int) -> None:
         with self._lock:
             self.programs_built += 1
+            self.segments_folded += folded
 
     def _drop(self, key: int, ref: weakref.ref) -> None:
         with self._lock:
@@ -589,12 +601,12 @@ programs = PlanPrograms()
 
 def execute_plan(plan: ExecutionPlan, inputs: Arrays, params: Arrays
                  ) -> Arrays:
-    """Tile-by-tile execution following the compiled plan, as the plan's
-    one jitted program.
+    """Execution following the compiled plan, as the plan's one jitted
+    program.
 
-    Segments are stitched with ``dynamic_update_slice`` (the concat helper);
-    supernodes run in the plan's scheduled order, which respects data
-    dependencies by construction (validated by ``validate_schedule``)."""
+    Kernel nodes run in the plan's scheduled order, which respects data
+    dependencies by construction (validated by ``validate_schedule``);
+    each op is computed whole by the first that covers it."""
     return programs.run(plan, [inputs], [params])[0]
 
 
@@ -612,34 +624,82 @@ def execute_multi_plan(plan, inputs_list: Sequence[Arrays],
     return programs.run(plan, inputs_list, params_list)
 
 
+def execute_plan_tiles(plan, inputs_list: Sequence[Arrays],
+                       params_list: Sequence[Arrays]) -> List[Arrays]:
+    """The plan's tile semantics, eagerly: each kernel node, in scheduled
+    order, computes its tile segment (:func:`run_supernode`), and each
+    op's segments are joined in tile order along its tile axis.  Raises
+    unless every split op's segments cover its tiles ``[0, T)`` once each,
+    in contiguous ranges.  Takes and returns one entry per tenant, for an
+    :class:`ExecutionPlan` as for a multi-tenant plan."""
+    tgs = plan_tenants(plan)
+    envs = [{**inputs_list[i], **params_list[i]} for i in range(len(tgs))]
+    sns = [{s.name: s for s in tg.supernodes} for tg in tgs]
+    # per tenant: split op output -> [(tile_lo, tile_hi, segment)]
+    parts: List[Dict[str, list]] = [{} for _ in tgs]
+    for t, name in plan_kernels(plan):
+        g, env, sn = tgs[t].graph, envs[t], sns[t][name]
+        for out, tile in run_supernode(g, sn, env).items():
+            ax = tile_axis(g, g.producer_of(out))
+            if ax is None or sn.full:
+                env[out] = tile
+                continue
+            segs = parts[t].setdefault(out, [])
+            segs.append((sn.tile_lo, sn.tile_hi, tile))
+            if sum(hi - lo for lo, hi, _ in segs) < sn.T:
+                continue
+            segs.sort(key=lambda seg: seg[0])
+            ranges = [(lo, hi) for lo, hi, _ in segs]
+            ends = [0] + [hi for _, hi in ranges]
+            if ends[-1] != sn.T or [lo for lo, _ in ranges] != ends[:-1]:
+                raise RuntimeError(f"{g.name}: segments {ranges} of {out} "
+                                   f"do not tile [0, {sn.T})")
+            env[out] = jnp.concatenate([v for _, _, v in segs], axis=ax)
+            if env[out].shape != tuple(g.tensors[out].shape):
+                raise RuntimeError(f"{g.name}: {out} joins to "
+                                   f"{env[out].shape}")
+            del parts[t][out]
+    outs = []
+    for tg, env, left in zip(tgs, envs, parts):
+        missing = [o for o in tg.graph.outputs if o not in env]
+        if left or missing:
+            raise RuntimeError(f"{tg.graph.name}: segments missing for "
+                               f"{sorted(left)}, outputs {missing}")
+        outs.append({o: env[o] for o in tg.graph.outputs})
+    return outs
+
+
+def _matches_oracle(plan, seed: int, atol: float, rtol: float) -> bool:
+    tgs = plan_tenants(plan)
+    params_list = [init_params(tg.graph, seed + 2 * i)
+                   for i, tg in enumerate(tgs)]
+    inputs_list = [init_inputs(tg.graph, seed + 2 * i + 1)
+                   for i, tg in enumerate(tgs)]
+    runs = {"program": programs.run(plan, inputs_list, params_list),
+            "tiles": execute_plan_tiles(plan, inputs_list, params_list)}
+    for i, tg in enumerate(tgs):
+        g = tg.graph
+        want = execute_graph(g, inputs_list[i], params_list[i])
+        for how, got in runs.items():
+            for t in g.outputs:
+                np.testing.assert_allclose(
+                    np.asarray(got[i][t]), np.asarray(want[t]),
+                    atol=atol, rtol=rtol,
+                    err_msg=f"{how}: tenant {i} ({g.name}) output {t}")
+    return True
+
+
 def plan_matches_oracle(plan: ExecutionPlan, seed: int = 0,
                         atol: float = 1e-4, rtol: float = 1e-4) -> bool:
-    g = plan.tiled.graph
-    params = init_params(g, seed)
-    inputs = init_inputs(g, seed + 1)
-    want = execute_graph(g, inputs, params)
-    got = execute_plan(plan, inputs, params)
-    for t in g.outputs:
-        np.testing.assert_allclose(np.asarray(got[t]), np.asarray(want[t]),
-                                   atol=atol, rtol=rtol)
-    return True
+    """Correctness contract: the plan's program (:func:`execute_plan`) and
+    its tile semantics (:func:`execute_plan_tiles`) both match the
+    direct whole-graph evaluation."""
+    return _matches_oracle(plan, seed, atol, rtol)
 
 
 def multi_plan_matches_oracle(plan, seed: int = 0, atol: float = 1e-4,
                               rtol: float = 1e-4) -> bool:
     """Multi-tenant correctness contract: the interleaved co-scheduled
-    execution matches every tenant's single-model oracle."""
-    inputs_list, params_list = [], []
-    for i, tg in enumerate(plan.tenants):
-        params_list.append(init_params(tg.graph, seed + 2 * i))
-        inputs_list.append(init_inputs(tg.graph, seed + 2 * i + 1))
-    got = execute_multi_plan(plan, inputs_list, params_list)
-    for i, tg in enumerate(plan.tenants):
-        g = tg.graph
-        want = execute_graph(g, inputs_list[i], params_list[i])
-        for t in g.outputs:
-            np.testing.assert_allclose(
-                np.asarray(got[i][t]), np.asarray(want[t]),
-                atol=atol, rtol=rtol,
-                err_msg=f"tenant {i} ({g.name}) output {t}")
-    return True
+    program (:func:`execute_multi_plan`) and the plan's tile semantics
+    both match every tenant's single-model oracle."""
+    return _matches_oracle(plan, seed, atol, rtol)

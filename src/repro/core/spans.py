@@ -18,7 +18,10 @@ planes by name, and their arguments among the event's stats.
 * ``repro.execute`` — the executor call of a wave (one per
   ``execute_plan`` on a floor round), one dispatch of the plan's jitted
   program; ``requests``, ``built`` (true when this call traced and
-  compiled, or loaded, that program: once per plan).
+  compiled, or loaded, that program: once per plan), ``folded`` (the
+  kernel nodes that program's trace folded,
+  ``PlanPrograms.segments_folded`` counted over the call; 0 when the
+  call built nothing).
 
 They nest ``step ⊃ wave ⊃ {plan, execute}`` on the thread that steps the
 engine.  A list of ids (``rids``, ``active``) is joined with :data:`SEP`:
@@ -33,10 +36,12 @@ it (``runtime.kernel_scope``), ``tenant`` being the model's graph name
 (the same in every plan the model runs in).  The scope is in the op
 metadata (``op_name``) of the compiled program's instructions, where a
 trace reader joins it to the device ops by instruction name; a fusion
-carries the scope of one of the ops it fused.  Inside a kernel's scope
-the ops of a sequence-model mechanism sit in one more,
-``repro.op:<op type>`` (``ssd``, ``causal_conv1d``, ``softmax`` when
-causal; ``runtime.op_scope``).
+carries the scope of one of the ops it fused.  The first kernel node
+that covers an op computes it whole, so a folded node, a tile segment
+of ops that earlier nodes computed, names no ops and its scope appears
+nowhere.  Inside a kernel's scope the ops of a sequence-model mechanism
+sit in one more, ``repro.op:<op type>`` (``ssd``, ``causal_conv1d``,
+``softmax`` when causal; ``runtime.op_scope``).
 """
 
 from __future__ import annotations

@@ -73,6 +73,20 @@ from repro.serve.admission import (AdmissionController, Priority,
 from repro.serve.compiler_thread import BackgroundCompiler
 
 
+def _executed(requests: int, run):
+    """``run()``, a wave's executor call, under its ``repro.execute``
+    span, which says whether the call built its plan's program and how
+    many of the plan's kernel nodes that build folded."""
+    from repro.core import runtime
+    progs = runtime.programs
+    with span(EXECUTE, requests=requests) as sp:
+        built, folded = progs.programs_built, progs.segments_folded
+        out = run()
+        sp.set_metadata(built=progs.programs_built > built,
+                        folded=progs.segments_folded - folded)
+    return out
+
+
 @dataclasses.dataclass
 class InferRequest:
     rid: int
@@ -602,13 +616,9 @@ class MultiModelEngine:
             reqs = [self._pop_head(i) for i in ids]
             outs = [None] * len(reqs)
             if self.execute:
-                with span(EXECUTE, requests=len(reqs)) as sp:
-                    built = runtime.programs.programs_built
-                    outs = runtime.execute_multi_plan(
-                        plan, [r.inputs for r in reqs],
-                        [self.params[r.tenant] for r in reqs])
-                    sp.set_metadata(
-                        built=runtime.programs.programs_built > built)
+                outs = _executed(len(reqs), lambda: runtime.execute_multi_plan(
+                    plan, [r.inputs for r in reqs],
+                    [self.params[r.tenant] for r in reqs]))
             if len(reqs) == 1:
                 self.solo_dispatches += 1
                 self.solo_rounds += 1
@@ -654,12 +664,8 @@ class MultiModelEngine:
                 splan = self.compiled.tenant_plan(i)
             out = None
             if self.execute:
-                with span(EXECUTE, requests=1) as sp:
-                    built = runtime.programs.programs_built
-                    out = runtime.execute_plan(splan, r.inputs,
-                                               self.params[i])
-                    sp.set_metadata(
-                        built=runtime.programs.programs_built > built)
+                out = _executed(1, lambda: runtime.execute_plan(
+                    splan, r.inputs, self.params[i]))
             self.solo_dispatches += 1
             self.busy_cycles += splan.makespan
             round_offset += splan.makespan

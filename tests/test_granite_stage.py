@@ -11,7 +11,9 @@ on the CPU, at small widths, against plain references.
   and served through ``DeploymentSession`` and
   ``MultiModelEngine(execute=True)``;
 * the MLPerf-Tiny deployments' plans, which the new ops must leave as
-  they were.
+  they were, and their tile semantics: every segment of the three-model
+  deployment's plans through ``runtime.run_supernode``, joined, against
+  the whole graph.
 
 Tolerances.  Every comparison is float32 against float32 or float64 on
 the CPU's exact float32 matmuls: what differs is summation order, about
@@ -23,6 +25,7 @@ The stage's logits are held to 1e-6 of their largest: above the 1e-7 to
 benchmark's control) reads at these widths.
 """
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -335,6 +338,22 @@ def test_served_stage_matches_the_reference(ref, nn, small_stage):
 # ---------------------------------------------------------------------------
 
 
+def _tiny_session(config):
+    """The benchmark's co-compiled MLPerf-Tiny deployment ``config``."""
+    with open(os.path.join(BENCH, "configs", f"{config}.json")) as f:
+        cfg = json.load(f)
+    session = DeploymentSession(CompileRequest(
+        graphs=[edge.ALL_MODELS[m]() for m in cfg["models"]],
+        soc=carfield_soc(), patterns=carfield_patterns(), **cfg["compile"]))
+    session.compile()
+    return session, len(cfg["models"])
+
+
+@pytest.fixture(scope="module")
+def tiny3():
+    return _tiny_session("mlperf-tiny3-carfield")[0]
+
+
 @pytest.mark.parametrize("config,kernels,makespan", [
     ("mlperf-tiny3-carfield", 63, 18_876_312.5),
     ("mlperf-tiny4-carfield", 124, 31_210_071.7),
@@ -342,16 +361,67 @@ def test_served_stage_matches_the_reference(ref, nn, small_stage):
 def test_tiny_full_house_plans_are_unchanged(config, kernels, makespan):
     """The benchmark's full-house plans, as its runs print them: kernel
     nodes and analytic makespan (cycles, to the printed 0.1)."""
-    with open(os.path.join(BENCH, "configs", f"{config}.json")) as f:
-        cfg = json.load(f)
-    session = DeploymentSession(CompileRequest(
-        graphs=[edge.ALL_MODELS[m]() for m in cfg["models"]],
-        soc=carfield_soc(), patterns=carfield_patterns(), **cfg["compile"]))
-    session.compile()
-    plan = session.try_plan_for(list(range(len(cfg["models"]))))
+    session, models = _tiny_session(config)
+    plan = session.try_plan_for(list(range(models)))
     got = sum(1 for n in plan.order if plan.nodes[n].kind == "kernel"
               and plan.nodes[n].supernode is not None)
     assert (got, round(float(plan.makespan), 1)) == (kernels, makespan)
+
+
+TINY3_PLANS = ["full-house", "autoencoder", "ds_cnn", "resnet"]
+
+
+def _tiny3_plan(session, which):
+    if which == "full-house":
+        return session.try_plan_for([0, 1, 2])
+    return session.compile().singles[TINY3_PLANS.index(which) - 1].plan
+
+
+@pytest.mark.parametrize("which", TINY3_PLANS)
+def test_tiny_plan_segments_stitch_to_the_oracle(tiny3, which):
+    """The plan's tile semantics, which the plan's program folds away:
+    the oracle drives each kernel node's segment through
+    ``runtime.run_supernode`` in scheduled order (conv halos,
+    neuron-tiled dense layers) and joins each op's segments in tile
+    order (``runtime.execute_plan_tiles``).  The full-house plan and each
+    model's compile-alone plan, all of which split some op."""
+    plan = _tiny3_plan(tiny3, which)
+    sns = [{s.name: s for s in tg.supernodes}
+           for tg in runtime.plan_tenants(plan)]
+    assert any(not sns[t][n].full for t, n in runtime.plan_kernels(plan))
+    if which == "full-house":
+        assert runtime.multi_plan_matches_oracle(plan)
+    else:
+        assert runtime.plan_matches_oracle(plan)
+
+
+@pytest.mark.parametrize("fault", ["overlap", "gap"])
+def test_segments_that_do_not_tile_an_op_are_refused(tiny3, fault):
+    """A plan whose segments of a split op overlap (one runs twice) or
+    leave a gap (one is left out) fails the oracle's tile execution,
+    where the program, which computes the op whole, would not see it."""
+    plan = _tiny3_plan(tiny3, "full-house")
+    sns = [{s.name: s for s in tg.supernodes}
+           for tg in runtime.plan_tenants(plan)]
+    order = list(plan.order)
+    k = next(i for i, name in enumerate(order)
+             if plan.nodes[name].kind == "kernel"
+             and plan.nodes[name].supernode is not None
+             and not sns[plan.nodes[name].tenant][
+                 plan.nodes[name].supernode].full)
+    if fault == "overlap":
+        order.insert(k, order[k])
+    else:
+        del order[k]
+    bad = dataclasses.replace(plan, order=order)
+    tgs = runtime.plan_tenants(bad)
+    args = ([runtime.init_inputs(tg.graph, 2 * i + 1)
+             for i, tg in enumerate(tgs)],
+            [runtime.init_params(tg.graph, 2 * i)
+             for i, tg in enumerate(tgs)])
+    # a gap shows where a consumer reads the op, or at the end
+    with pytest.raises((RuntimeError, KeyError)):
+        runtime.execute_plan_tiles(bad, *args)
 
 
 def test_default_attributes_leave_existing_ops_bitwise():

@@ -3,9 +3,10 @@
 Small ``two_acc_soc`` deployments on the CPU: a plan is traced and
 compiled once, on its first execution, and every later execution of it,
 with any weights, reuses that program; the program dies with its plan;
-each kernel node's ops sit under their own ``repro.kernel`` named scope;
-and what the engine serves through the programs matches the eager oracle
-``execute_graph``.
+each kernel node that computes an op sits under its own ``repro.kernel``
+named scope, and the tile segments of ops an earlier node computed whole
+are counted as folded; and what the engine serves through the programs
+matches the eager oracle ``execute_graph``.
 """
 
 import dataclasses
@@ -15,6 +16,7 @@ import weakref
 
 import numpy as np
 import pytest
+from _plans import folded_nodes
 
 from repro.core import runtime
 from repro.core.deploy import CompileRequest, DeploymentSession
@@ -42,11 +44,6 @@ def make_session(**kw) -> DeploymentSession:
 @pytest.fixture(scope="module")
 def session():
     return make_session()
-
-
-def kernel_nodes(plan):
-    return [n for n in plan.nodes.values()
-            if n.kind == "kernel" and n.supernode is not None]
 
 
 def close(got, want) -> None:
@@ -109,18 +106,23 @@ def test_an_evicted_plan_frees_its_program():
 
 
 def test_each_kernel_node_is_one_named_scope(session):
-    plan = session.compile().plan
+    # a copy: a plan no other test has traced, so this lowering traces it
+    plan = dataclasses.replace(session.compile().plan)
     graphs = [tg.graph for tg in plan.tenants]
     args = ([init_inputs(g, 1) for g in graphs],
             [init_params(g, 0) for g in graphs])
+    folded = runtime.programs.segments_folded
     text = runtime.programs.program(plan).lower(*args).as_text(
         debug_info=True)
+    folded = runtime.programs.segments_folded - folded
     found = set(re.findall(r"repro\.kernel:[A-Za-z0-9_@.]+:[A-Za-z0-9_@.]+",
                            text))
-    want = {kernel_scope(graphs[n.tenant].name, n.supernode)
-            for n in kernel_nodes(plan)}
-    assert len(want) == len(kernel_nodes(plan))
+    skipped = folded_nodes(plan)
+    assert skipped                  # the plan splits ops into segments
+    want = {kernel_scope(graphs[t].name, sn)
+            for t, sn in runtime.plan_kernels(plan) if (t, sn) not in skipped}
     assert found == want
+    assert len(found) + folded == len(runtime.plan_kernels(plan))
 
 
 def test_engine_answers_match_the_eager_oracle(session):

@@ -8,9 +8,10 @@ arguments tie it to its request and its plan, and a running profiler
 changes neither the answers nor the engine's counters.  A plan runs as
 one jitted program, so ``repro.kernel`` is a named scope inside it and no
 host span (``tests/test_plan_program.py``); ``repro.execute`` says which
-call built its plan's program.  A language-model stage puts its tokens on
-``repro.submit`` and ``repro.wave``, and its mechanisms' ops in
-``repro.op`` scopes inside their kernels'.
+call built its plan's program, and how many kernel nodes that build
+folded.  A language-model stage puts its tokens on ``repro.submit`` and
+``repro.wave``, and its mechanisms' ops in ``repro.op`` scopes inside
+their kernels'.
 """
 
 import glob
@@ -20,6 +21,7 @@ import re
 import jax
 import numpy as np
 import pytest
+from _plans import folded_nodes
 
 from repro.core import runtime, spans
 from repro.core.deploy import CompileRequest, DeploymentSession
@@ -167,6 +169,11 @@ def test_spans_nest_and_tie_to_requests_and_plans(tmp_path, monkeypatch,
         seen.add(id(plan))
     assert [bool(ev[3]["built"]) for ev in by[spans.EXECUTE]] == firsts
     assert firsts.count(True) < len(firsts)     # some call reused one
+    # and says how many kernel nodes that build folded
+    folded = [len(folded_nodes(p)) if first else 0
+              for p, first in zip(ran, firsts)]
+    assert [ev[3]["folded"] for ev in by[spans.EXECUTE]] == folded
+    assert any(folded)
 
 
 def test_a_running_profiler_changes_no_answer_and_no_counter(tmp_path):

@@ -9,14 +9,17 @@ The widths are those of ``repro.kernels.cases``, which the kernel phase
 of ``chip_smoke.py`` runs on the chip.  The plan program is that of a
 small ``two_acc_soc`` co-schedule: what the executor hands the chip's
 compiler for every plan it runs; and that of a small granite stage, whose
-sequence-model ops have to compile for the chip too.
+sequence-model ops have to compile for the chip too, and whose tiled ops
+the program computes once each.
 """
 
+import dataclasses
 import os
 import re
 
 import jax
 import pytest
+from _plans import folded_nodes
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
@@ -95,27 +98,59 @@ def test_plan_program_compiles_for_v5e(one_chip, no_persistent_cache):
     assert found and found <= want, (found, want)
 
 
-def test_stage_plan_program_compiles_for_v5e(one_chip, no_persistent_cache):
-    """A small granite stage's plan on the Carfield preset: its ssd,
-    causal conv and causal softmax compile for the chip, and each sits in
-    its ``repro.op`` scope inside its kernel's."""
+@pytest.fixture(scope="module")
+def stage():
+    """A small granite stage and its plan on the Carfield preset.  At four
+    Mamba heads the plan splits the ``ssd`` and the projections into tile
+    segments over PULP, Spatz and the host, as it does the full stage."""
     g = granite_h_micro_stage4(
-        seq=128, chunk=64, d=64, heads=2, head_dim=32, state=16,
+        seq=128, chunk=64, d=64, heads=4, head_dim=16, state=16,
         q_heads=2, kv_heads=1, attn_head_dim=32, ffn=128, vocab=256,
         pattern="ma")
     session = DeploymentSession(CompileRequest(
         graphs=[g], soc=carfield_soc(), patterns=carfield_patterns(),
         time_budget_s=0.001, joint_time_budget_s=0.001,
         lazy_joint_time_budget_s=0.001, incremental_time_budget_s=0.001))
-    plan = session.compile().plan
+    return g, session.compile().plan
 
+
+def stage_args(g, sharding):
     def shapes(names):
         return {n: jax.ShapeDtypeStruct(g.tensors[n].shape, "float32",
-                                        sharding=one_chip) for n in names}
+                                        sharding=sharding) for n in names}
 
-    params = shapes([n for n, t in g.tensors.items() if t.kind == "param"])
+    return ([shapes(g.inputs)],
+            [shapes([n for n, t in g.tensors.items() if t.kind == "param"])])
+
+
+def test_stage_plan_program_compiles_for_v5e(stage, one_chip,
+                                             no_persistent_cache):
+    """The stage's ssd, causal conv and causal softmax compile for the
+    chip, and each sits in its ``repro.op`` scope inside its kernel's."""
+    g, plan = stage
     text = runtime.programs.program(plan).lower(
-        [shapes(g.inputs)], [params]).compile().as_text()
+        *stage_args(g, one_chip)).compile().as_text()
     found = set(re.findall(r"repro\.kernel:[^/\"]*/(repro\.op:\w+)", text))
     assert found == {"repro.op:ssd", "repro.op:causal_conv1d",
                      "repro.op:softmax"}
+
+
+def test_stage_plan_program_computes_each_tiled_op_once(stage):
+    """The program computes each op whole at its first segment: the
+    segments the trace folds are those ``folded_nodes`` counts, the
+    program holds one scan loop per ``ssd`` and stitches no tiles.
+    Lowered only, for no chip in particular."""
+    g, plan = stage
+    # a copy: a plan no other test has traced, so this lowering traces it
+    plan = dataclasses.replace(plan)
+    folded = runtime.programs.segments_folded
+    text = runtime.programs.program(plan).lower(
+        *stage_args(g, None)).as_text(debug_info=True)
+    assert runtime.programs.segments_folded - folded == len(
+        folded_nodes(plan)) > 0
+    ssd = [op for op in g.ops.values() if op.op_type == "ssd"]
+    assert len(ssd) == 1
+    assert text.count("stablehlo.while") == len(ssd)
+    updates = re.findall(
+        r'loc\("([^"]*repro\.kernel:[^"]*dynamic_update_slice)"', text)
+    assert all("/while/body/" in u for u in updates), updates

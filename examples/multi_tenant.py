@@ -111,7 +111,7 @@ grows three opt-in layers (all default-off; the bare engine stays FIFO):
 
 ``submit`` returns ``None`` for an admission-rejected request;
 ``report()`` adds per-class attainment/percentiles, round decomposition
-(co / solo / fallback / floor rounds), starvation events (structurally 0)
+(co / solo / floor rounds), starvation events (structurally 0)
 and the admission/composer/background-compiler counters.
 
 The legacy one-shot wrapper (``compile_multi``) is demoed at the end for
@@ -150,8 +150,7 @@ default) raises on any ERROR diagnostic so a hazardous plan can never
 be cached or served, ``"warn"`` records diagnostics in
 ``session.analysis_stats()`` (surfaced under ``report()["analysis"]``
 by the serving engine) but ships the plan, ``"off"`` skips analysis.
-The legacy ``validate_schedule`` / ``validate_multi_schedule`` /
-``validate_plan`` helpers are now thin shims over the same analyzer.
+``repro.analysis.analyze_errors(plan)`` runs the same rules on any plan.
 
     PYTHONPATH=src python examples/multi_tenant.py
 """

@@ -6,12 +6,8 @@ human-readable message, and enough structure (nodes, tensors, resource,
 tenant, time window) for tooling to group, count, and gate on findings
 without parsing message text.
 
-The shared ``TIME_EPS`` lives here too: historically
-``schedule.validate_schedule`` / ``validate_multi_schedule`` used a
-``1e-6``-cycle slack while ``memplan.validate_plan`` compared with strict
-inequalities — three checkers, two epsilon conventions.  All interval
-overlap tests in the analyzer (and, through the wrapper shims, in the
-legacy validators) now agree: two half-open intervals ``[a0, a1)`` and
+The shared ``TIME_EPS`` lives here too: every interval overlap test
+in the analyzer agrees that two half-open intervals ``[a0, a1)`` and
 ``[b0, b1)`` conflict iff each starts more than ``TIME_EPS`` before the
 other ends.
 """

@@ -5,9 +5,9 @@ Runs linter-style rules (``PA001``..``PA008``, see
 ``ExecutionPlan``, a multi-tenant ``MultiExecutionPlan``, or a bare
 ``MemoryPlan``.  The analyzer is deliberately *duck-typed* — it reads
 only plain plan attributes (``nodes``, ``dmas``, ``memory``,
-``tenants``, ``budgets``, ...) and never imports the scheduler, so
-``core.schedule`` / ``core.memplan`` can call it from their legacy
-validator shims without an import cycle.
+``tenants``, ``budgets``, ...); of the scheduler it imports only the
+DMA engine's name and the namespaced-name parser.  In ``core`` only
+``core.deploy``, the deployment session that runs it, imports it.
 
 Why a static pass at all: the analytic simulator produces correct
 *numerics* even for a racy plan (it executes tenants' kernels in
@@ -37,10 +37,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from repro.analysis.diagnostics import (TIME_EPS, Diagnostic, Severity,
                                         errors_only)
-
-#: The single system DMA engine's resource name (mirrors
-#: ``schedule.DMA`` without importing the scheduler).
-DMA = "dma"
+from repro.core.schedule import DMA, split_namespaced
 
 #: Single-model plan modes that promise global sequential execution.
 SEQUENTIAL_MODES = ("tvm", "match")
@@ -62,15 +59,6 @@ def _streamed_tensors(nodes) -> Set[str]:
         for t, _dirn, _b in n.l3_traffic:
             out.add(t)
     return out
-
-
-def _tenant_of(name: str) -> Optional[int]:
-    """Tenant index from a namespaced ``t{i}/...`` name, else None."""
-    if name.startswith("t"):
-        head, sep, _ = name.partition("/")
-        if sep and head[1:].isdigit():
-            return int(head[1:])
-    return None
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +330,8 @@ def _check_isolation(plan) -> List[Diagnostic]:
             f"{len(budgets)} L2 budgets for {n_tenants} tenants"))
         budgets = []
     for a in plan.memory.allocations:
-        ns = _tenant_of(a.tensor)
+        parsed = split_namespaced(a.tensor)
+        ns = parsed[0] if parsed is not None else None
         if ns is not None and ns != a.owner:
             diags.append(Diagnostic(
                 "PA006", Severity.ERROR,
@@ -402,7 +391,7 @@ def _check_isolation(plan) -> List[Diagnostic]:
 
 
 def analyze_memory(memory) -> List[Diagnostic]:
-    """PA005 over a bare ``MemoryPlan`` (the ``validate_plan`` shim)."""
+    """PA005 over a bare ``MemoryPlan``."""
     return _check_aliasing(memory)
 
 

@@ -59,6 +59,7 @@ from collections import OrderedDict
 from typing import (Callable, Dict, FrozenSet, Hashable, List, Mapping,
                     Optional, Sequence, Set, Tuple)
 
+from repro.analysis import Severity, analyze, analyze_errors
 from repro.core import cpsolver
 from repro.core.decompose import solve_decomposed
 from repro.core.ir import Graph
@@ -69,8 +70,7 @@ from repro.core.shapes import (PlanKey, ShapeBucketSpec, StoreKey,
                                key_parts, key_sort, make_plan_key)
 from repro.core.schedule import (ExecutionPlan, MultiExecutionPlan,
                                  concat_plans, contention_hints,
-                                 default_budgets, schedule, schedule_multi,
-                                 validate_schedule)
+                                 default_budgets, schedule, schedule_multi)
 from repro.core.tiling import (Contention, JointTilingProblem,
                                TilingSolution, optimize_tiling,
                                solution_ws_bytes, tile_granularities)
@@ -242,6 +242,16 @@ class Objective:
 @dataclasses.dataclass
 class CompileRequest:
     """Everything a :class:`DeploymentSession` needs, as one typed value.
+
+    Stage 1 tiles each model alone; stage 2 merges the N execution DAGs
+    under shared-resource constraints (per-device mutual exclusion, one
+    double-buffered DMA engine, a shared L2 with per-tenant budgets).
+    With ``retile_for_contention`` the session then iterates contention
+    hints -> per-tenant re-tiling -> exact re-arbitration to a fixpoint,
+    followed by the joint cross-tenant solve.  The sequential
+    concatenation of the single-model schedules stays a candidate
+    throughout, so ``joint <= best-response <= re-tiling-free
+    co-schedule <= sequential``.
 
     ``budgets`` fixes the per-tenant shared-L2 split (default: equal split
     among however many tenants are active in a given plan); ``strategies``
@@ -748,21 +758,18 @@ class MultiCompiledModel:
     the merged resource-constrained co-schedule, whose tilings may be the
     compile-alone ones or a contention-aware re-tiling (whichever gave the
     better objective); ``baseline_plan`` is the co-schedule restricted to
-    the compile-alone tilings (the pre-re-tiling behaviour).  When built by
-    a :class:`DeploymentSession` (the normal path), ``plan_for`` and
-    ``tenant_plan`` route through the session's occupancy-indexed
+    the compile-alone tilings (the pre-re-tiling behaviour).  ``session``
+    is the :class:`DeploymentSession` that built it: ``plan_for`` and
+    ``tenant_plan`` route through its occupancy-indexed
     :class:`PlanStore`, so partial occupancy gets a real (cached) subset
-    co-schedule instead of ``None``."""
+    co-schedule."""
     graphs: List[Graph]
     soc: SoC
     mode: str
     singles: List[CompiledModel]
     plan: MultiExecutionPlan
+    session: "DeploymentSession" = dataclasses.field(repr=False)
     baseline_plan: Optional[MultiExecutionPlan] = None
-    session: Optional["DeploymentSession"] = \
-        dataclasses.field(default=None, repr=False)
-    _tenant_plans: Optional[List[Optional[ExecutionPlan]]] = \
-        dataclasses.field(default=None, repr=False)
 
     @property
     def makespan_cycles(self) -> float:
@@ -790,8 +797,7 @@ class MultiCompiledModel:
         PR 2/3 behaviour — phase A of the session's fixpoint, before the
         joint cross-tenant solve).  By construction
         ``plan.makespan <= best_response <= baseline <= sequential``."""
-        if self.session is not None and \
-                self.session.best_response_plan is not None:
+        if self.session.best_response_plan is not None:
             return self.session.best_response_plan.makespan
         return self.plan.makespan
 
@@ -800,16 +806,11 @@ class MultiCompiledModel:
         full-house tiling) — see :meth:`DeploymentSession.reference_plan`."""
         if tg is None or tg is self.plan.tenants[i]:
             return self.tenant_plan(i)
-        if self.session is not None:
-            return self.session.reference_plan(i, tg)
-        raise ValueError("session-less artifact has no per-occupancy "
-                         "reference plans")
+        return self.session.reference_plan(i, tg)
 
-    def joint_stats(self) -> Optional[Dict[str, int]]:
-        """Joint cross-tenant solver counters (``None`` for session-less
-        artifacts): successful solves and best-response fallbacks."""
-        if self.session is None:
-            return None
+    def joint_stats(self) -> Dict[str, int]:
+        """Joint cross-tenant solver counters: successful solves and
+        best-response fallbacks."""
         return {"solves": self.session.joint_solves,
                 "fallbacks": self.session.joint_fallbacks}
 
@@ -837,19 +838,10 @@ class MultiCompiledModel:
         the cached schedule instead of re-deriving it)."""
         if self.plan.tenants[i] is self.singles[i].tiled:
             return self.singles[i].plan
-        if self.session is not None:
-            return self.session.tenant_plan(i)
-        # legacy path for hand-built artifacts without a session
-        if self._tenant_plans is None:
-            self._tenant_plans = [None] * len(self.graphs)
-        if self._tenant_plans[i] is None:
-            self._tenant_plans[i] = schedule(self.plan.tenants[i], self.soc,
-                                             self.mode, restarts=1,
-                                             anneal_iters=0)
-        return self._tenant_plans[i]
+        return self.session.tenant_plan(i)
 
     def plan_for(self, active: Sequence[int],
-                 shapes=None) -> Optional[MultiExecutionPlan]:
+                 shapes=None) -> MultiExecutionPlan:
         """Co-schedule covering exactly the ``active`` tenants (at the
         optional per-tenant sequence ``shapes`` — tenant -> bucket).
 
@@ -857,14 +849,10 @@ class MultiCompiledModel:
         pre-compiled subsets hit the cache, anything else is compiled
         lazily and cached, so *every* non-empty occupancy gets a validated
         co-schedule.  Tenant indices inside the returned plan are
-        positional over ``sorted(set(active))``.  Returns ``None`` only on
-        a session-less artifact asked for a partial occupancy (the legacy
-        behaviour)."""
+        positional over ``sorted(set(active))``."""
         ids = sorted({int(a) for a in active})
         if not shapes and ids == list(range(len(self.graphs))):
             return self.plan
-        if self.session is None:
-            return None
         return self.session.plan_for(ids, shapes=shapes)
 
     def try_plan_for(self, active: Sequence[int], touch: bool = False,
@@ -872,20 +860,15 @@ class MultiCompiledModel:
         """Non-blocking occupancy lookup: the cached plan or ``None`` —
         never compiles (delegates to
         :meth:`DeploymentSession.try_plan_for`, including the ``touch``
-        accounting and the optional ``shapes`` buckets).  On a
-        session-less artifact only the full house answers."""
+        accounting and the optional ``shapes`` buckets)."""
         ids = sorted({int(a) for a in active})
         if not shapes and ids == list(range(len(self.graphs))):
             return self.plan
-        if self.session is None:
-            return None
         return self.session.try_plan_for(ids, touch=touch, shapes=shapes)
 
-    def store_stats(self) -> Optional[Dict[str, int]]:
-        """Hit/miss/compile counters of the session's plan store (``None``
-        for session-less artifacts)."""
-        return (self.session.store.stats()
-                if self.session is not None else None)
+    def store_stats(self) -> Dict[str, int]:
+        """Hit/miss/compile counters of the session's plan store."""
+        return self.session.store.stats()
 
     def run(self, inputs_list, params_list):
         from repro.core.runtime import execute_multi_plan
@@ -1306,7 +1289,7 @@ class DeploymentSession:
                 plan = schedule(tg, req.soc, spec.stage1)
             except Exception:
                 return None
-        if validate_schedule(plan):
+        if analyze_errors(plan):
             return None
         return sol, tg, plan
 
@@ -1366,7 +1349,6 @@ class DeploymentSession:
         mode = self.request.analysis
         if mode == "off":
             return plan
-        from repro.analysis import Severity, analyze
         diags = analyze(plan)
         errors = [d for d in diags if d.severity >= Severity.ERROR]
         with self._lock:
@@ -1412,7 +1394,7 @@ class DeploymentSession:
         self._analyze(plan, "infeasible co-schedule")
         mc = MultiCompiledModel(graphs=list(req.graphs), soc=req.soc,
                                 mode=req.mode, singles=singles, plan=plan,
-                                baseline_plan=baseline, session=self)
+                                session=self, baseline_plan=baseline)
         self.store.seed(range(len(req.graphs)), plan)
         self.store.protect(range(len(req.graphs)))
         self._record_solutions(list(range(len(req.graphs))), plan)

@@ -12,8 +12,8 @@ serializes them on the system DMA engine and accounts for them in the
 makespan (the paper's current model does not overlap DMA with compute).
 
 The resulting plan is a set of ``(tensor, address, size, t_alloc, t_free)``
-rectangles; :func:`validate_plan` asserts the packing is overlap-free, which
-is property-tested.
+rectangles; the static plan analyzer's PA005 rule (``analyze_memory``)
+checks that the packing is overlap-free, which is property-tested.
 """
 
 from __future__ import annotations
@@ -245,17 +245,3 @@ class MemoryPlan:
 
     def static_tensors(self) -> List[str]:
         return [a.tensor for a in self.allocations if a.strategy == "static"]
-
-
-def validate_plan(plan: MemoryPlan) -> List[str]:
-    """Returns a list of violations (empty == valid packing).
-
-    A thin shim over the static plan analyzer's PA005 aliasing rule
-    (:func:`repro.analysis.analyze_memory`): a sweep-line over the
-    allocation rectangles flags address overlap between concurrently-live
-    allocations and out-of-L2-range placements.  Historically this
-    checker used strict inequalities for the time overlap while the
-    schedule validators allowed ``1e-6`` slack; all three now share the
-    analyzer's single ``TIME_EPS``."""
-    from repro.analysis import analyze_errors
-    return [str(d) for d in analyze_errors(plan)]

@@ -605,7 +605,7 @@ def execute_plan(plan: ExecutionPlan, inputs: Arrays, params: Arrays
     program.
 
     Kernel nodes run in the plan's scheduled order, which respects data
-    dependencies by construction (validated by ``validate_schedule``);
+    dependencies by construction (checked by ``analyze_errors``);
     each op is computed whole by the first that covers it."""
     return programs.run(plan, [inputs], [params])[0]
 

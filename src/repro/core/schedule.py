@@ -13,10 +13,11 @@ minimum-makespan schedule subject to:
     behaviour where constrained memory forces serialization.
 
 Search: priority-list scheduling (HEFT-style upward ranks) with several
-priority schemes + seeded perturbations; every candidate is validated against
-the constraint set and the best feasible makespan wins.  Sequential modes
-(tvm / match) additionally serialize all compute on a global mutex, which is
-how the paper's baselines execute (§4).
+priority schemes + seeded perturbations; every candidate is simulated under
+the constraint set and the best feasible makespan wins (the static plan
+analyzer checks each plan a deployment ships).  Sequential modes (tvm /
+match) additionally serialize all compute on a global mutex, which is how
+the paper's baselines execute (§4).
 """
 
 from __future__ import annotations
@@ -615,16 +616,6 @@ def schedule(tg: TiledGraph, soc: SoC, mode: str,
     return best
 
 
-def validate_schedule(plan: ExecutionPlan) -> List[str]:
-    """Constraint checker, now a thin shim over the static plan analyzer
-    (:mod:`repro.analysis`): precedence and per-resource mutual exclusion
-    as before, plus DMA/compute data hazards, use-after-evict, L2 address
-    aliasing, and double-buffer discipline — every rule at one shared
-    ``TIME_EPS``.  Returns ERROR findings as strings (empty == valid)."""
-    from repro.analysis import analyze_errors
-    return [str(d) for d in analyze_errors(plan)]
-
-
 # ---------------------------------------------------------------------------
 # Multi-tenant co-scheduling (inter-model concurrency)
 # ---------------------------------------------------------------------------
@@ -653,6 +644,22 @@ def _check_budgets(budgets: Sequence[int], n_tenants: int) -> List[int]:
     return budgets
 
 
+def namespaced(tenant: int, name: str) -> str:
+    """``name`` inside a co-schedule, in tenant ``tenant``'s namespace:
+    ``t{tenant}/{name}`` (``namespaced(i, "")`` is the bare prefix)."""
+    return f"t{tenant}/{name}"
+
+
+def split_namespaced(name: str) -> Optional[Tuple[int, str]]:
+    """``(tenant, name)`` of a name built by :func:`namespaced`; ``None``
+    unless ``name`` has the form ``t<digits>/<rest>``."""
+    head, sep, rest = name.partition("/")
+    digits = head[1:]
+    if sep and head[:1] == "t" and digits.isascii() and digits.isdigit():
+        return int(digits), rest
+    return None
+
+
 def _namespace_node(n: PlanNode, prefix: str, tenant: int) -> PlanNode:
     """Copy of ``n`` with every node/tensor reference prefixed for its
     tenant (shared by the co-scheduler DAG merge and the sequential
@@ -676,7 +683,7 @@ def build_multi_dag(tgs: Sequence[TiledGraph], soc: SoC,
     budgets = _check_budgets(budgets, len(tgs))
     merged: Dict[str, PlanNode] = {}
     for i, tg in enumerate(tgs):
-        p = f"t{i}/"
+        p = namespaced(i, "")
         for name, n in build_dag(tg, soc, l2_budget=budgets[i]).items():
             merged[p + name] = _namespace_node(n, p, i)
     return merged
@@ -725,7 +732,7 @@ class _MultiSimState:
         self.state: Dict[str, str] = {}
         self.outputs: Set[str] = set()
         for i, tg in enumerate(tgs):
-            p = f"t{i}/"
+            p = namespaced(i, "")
             g = tg.graph
             for t, ti in g.tensors.items():
                 self.tensors[p + t] = ti
@@ -941,7 +948,7 @@ def concat_plans(singles: Sequence[ExecutionPlan], soc: SoC,
     tenant_ms: List[float] = []
     offset = 0.0
     for i, plan in enumerate(singles):
-        p = f"t{i}/"
+        p = namespaced(i, "")
         for name, n in plan.nodes.items():
             nodes[p + name] = dataclasses.replace(
                 _namespace_node(n, p, i),
@@ -1028,8 +1035,6 @@ def _search_coschedule(tgs: Sequence[TiledGraph], soc: SoC,
         except (MemoryError, RuntimeError) as e:
             last_err = e
             continue
-        if validate_multi_schedule(plan):
-            continue
         if best is None or (objective.better(plan, best)
                             if objective is not None
                             else plan.makespan < best.makespan):
@@ -1102,26 +1107,6 @@ def schedule_multi(tgs: Sequence[TiledGraph], soc: SoC,
     return best
 
 
-def validate_multi_schedule(plan: MultiExecutionPlan) -> List[str]:
-    """Co-schedule constraint checker, now a thin shim over the static
-    plan analyzer (:mod:`repro.analysis`).  Beyond the historical checks
-    (precedence, per-device mutual exclusion, single-DMA-engine
-    exclusivity across all tenants' explicit load/store nodes and inline
-    transfers, tenant completion within the makespan) this validates L2
-    *address* aliasing across concurrently-live allocations — memory
-    overlap across tenants used to be unchecked in multi plans — plus
-    DMA/compute data hazards, use-after-evict, double-buffer discipline,
-    and tenant budget isolation.  Returns ERROR findings as strings
-    (empty == valid)."""
-    from repro.analysis import analyze_errors
-    return [str(d) for d in analyze_errors(plan)]
-
-
-def _tenant_of(namespaced: str) -> int:
-    """Tenant index from a namespaced node/tensor name ``t{i}/...``."""
-    return int(namespaced[1:namespaced.index("/")])
-
-
 def contention_hints(plan: MultiExecutionPlan, soc: SoC) -> List:
     """Summarize a merged co-schedule into per-tenant
     :class:`repro.core.tiling.Contention` contexts for re-tiling.
@@ -1143,7 +1128,7 @@ def contention_hints(plan: MultiExecutionPlan, soc: SoC) -> List:
         busy[nd.tenant][nd.resource] = \
             busy[nd.tenant].get(nd.resource, 0.0) + nd.duration
     for d in plan.dmas:
-        dma_busy[_tenant_of(d.tensor)] += d.end - d.start
+        dma_busy[split_namespaced(d.tensor)[0]] += d.end - d.start
     hints = []
     for i in range(n):
         load: Dict[str, float] = {}

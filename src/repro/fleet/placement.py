@@ -236,19 +236,15 @@ class PlanCache:
             return self._mcs[key]
 
     def compiler_for(self, names: Sequence[str]
-                     ) -> Optional[BackgroundCompiler]:
+                     ) -> BackgroundCompiler:
         """The mix's shared background compile pool (built on first use
         over the mix's shared session, ``config.max_workers`` threads,
-        prefetcher per ``config.prefetch``).  Returns ``None`` when the
-        compiled artifact carries no session.  Sharing one compiler per
+        prefetcher per ``config.prefetch``).  Sharing one compiler per
         mix is the fleet-wide dedup: a compile key queued or in flight
         for *any* SoC hosting the mix bounces every other SoC's submit
         of the same key."""
         key = self.key_for(names)
-        mc = self.mc_for(key)
-        session = getattr(mc, "session", None)
-        if session is None:
-            return None
+        session = self.mc_for(key).session
         with self._lock:
             got = self._compilers.get(key)
             if got is None:

@@ -265,8 +265,7 @@ class FleetRebalancer:
             warm.append(dst.mc.session)
         wall = dst.host(new_mix, at_s=at_s, warm_from=warm)
         info = self.fleet.cache.build_info(new_mix) or {}
-        stats = (dst.mc.session.analysis_stats()
-                 if dst.mc.session is not None else {"errors": 0})
+        stats = dst.mc.session.analysis_stats()
         rec = MigrationRecord(
             class_name=class_name, src_soc=src.soc_id,
             dst_soc=dst.soc_id, at_s=at_s, recovery_s=wall,

@@ -7,8 +7,9 @@ co-scheduled rounds — every round executes the plan covering exactly that
 occupancy (``plan_for(active)``, answered from the session's
 occupancy-indexed plan store), including singleton occupancies, whose
 one-tenant plan is never worse than the full-house reference schedule.
-The compile-alone back-to-back fallback remains only for session-less
-artifacts.
+A round is one of two kinds: a plan round runs that occupancy plan, and
+a floor round (a background-compile miss) runs the tenants' compile-alone
+schedules back to back.
 
 LM tenants ride the same engine since the shape-bucket rework: a request
 may carry a ``seq_len``, which the tenant's
@@ -65,6 +66,7 @@ from typing import Any, Dict, List, Mapping, Optional
 
 import numpy as np
 
+from repro.core.schedule import split_namespaced
 from repro.core.spans import (EXECUTE, PLAN, STEP, SUBMIT, WAVE, joined,
                               span)
 from repro.serve.admission import (AdmissionController, Priority,
@@ -130,9 +132,11 @@ class MultiModelEngine:
     (``plan_for`` from the session's occupancy-indexed plan store) — the
     active models advance concurrently and the round costs that
     co-schedule's makespan; a lone active tenant runs its cached singleton
-    occupancy plan (falling back to the single-model reference schedule on
-    session-less artifacts).  Per-request latency is taken from the
-    analytic schedule model (cycles -> ms at the SoC clock).
+    occupancy plan.  With a background compiler attached, a store miss
+    makes the round a floor round instead: the compile-alone schedules,
+    back to back, while the occupancy plan compiles.  Per-request latency
+    is taken from the analytic schedule model (cycles -> ms at the SoC
+    clock).
 
     Optional layers (all off by default — the default engine is bitwise
     the FIFO engine):
@@ -185,7 +189,6 @@ class MultiModelEngine:
         self.co_rounds = 0
         self.subset_co_rounds = 0     # co-rounds at partial occupancy
         self.solo_rounds = 0          # singleton occupancy-plan rounds
-        self.fallback_rounds = 0      # session-less back-to-back rounds
         self.floor_rounds = 0         # async-miss compile-alone floor rounds
         self.batched_repeat_rounds = 0
         self.solo_dispatches = 0
@@ -197,15 +200,11 @@ class MultiModelEngine:
         self.max_batch = max_batch
         self._slo_seen = False        # any request ever carried an SLO
         self.class_submitted: Dict[Priority, int] = {p: 0 for p in Priority}
-        session = getattr(compiled, "session", None)
-        self.session = session
-        if async_compile and session is None:
-            raise ValueError("async_compile needs a session-backed "
-                             "compiled artifact")
+        self.session = compiled.session
         if isinstance(async_compile, BackgroundCompiler):
             self.compiler: Optional[BackgroundCompiler] = async_compile
         elif async_compile:
-            self.compiler = BackgroundCompiler(session)
+            self.compiler = BackgroundCompiler(self.session)
         else:
             self.compiler = None
 
@@ -234,16 +233,14 @@ class MultiModelEngine:
     def _resolve_bucket(self, tenant: int,
                         seq_len: Optional[int]) -> Optional[int]:
         """Round ``seq_len`` up to the tenant's shape bucket (``None``
-        for shapeless requests).  Requires a session-backed artifact with
-        a :class:`~repro.core.shapes.ShapeBucketSpec` for the tenant."""
+        for shapeless requests).  Requires a
+        :class:`~repro.core.shapes.ShapeBucketSpec` for the tenant."""
         if seq_len is None:
             return None
-        spec = (self.session.bucket_spec(tenant)
-                if self.session is not None else None)
+        spec = self.session.bucket_spec(tenant)
         if spec is None:
             raise ValueError(f"tenant {tenant} takes no seq_len: no "
-                             f"shape_buckets spec (session-backed "
-                             f"artifacts only)")
+                             f"shape_buckets spec")
         return spec.bucket_for(seq_len)
 
     def _tokens(self, tenant: int, seq_len: Optional[int]) -> int:
@@ -400,16 +397,13 @@ class MultiModelEngine:
     def _probe(self) -> RoundPlanProbe:
         heads = {i: self.queues[i][self._edf_index(i)]
                  for i in range(self.n_tenants) if self.queues[i]}
-        if self.session is not None:
-            buckets = {i: r.bucket for i, r in heads.items()
-                       if r.bucket is not None}
+        buckets = {i: r.bucket for i, r in heads.items()
+                   if r.bucket is not None}
 
-            def try_plan(ids, touch: bool = False):
-                sh = {i: buckets[i] for i in ids if i in buckets}
-                return self.session.try_plan_for(ids, touch=touch,
-                                                 shapes=sh or None)
-        else:
-            try_plan = None
+        def try_plan(ids, touch: bool = False):
+            sh = {i: buckets[i] for i in ids if i in buckets}
+            return self.session.try_plan_for(ids, touch=touch,
+                                             shapes=sh or None)
         return RoundPlanProbe(
             try_plan=try_plan, cycles_to_s=self._cycles_to_s,
             floors_s={i: (self._req_floor_s(heads[i]) if i in heads
@@ -437,10 +431,9 @@ class MultiModelEngine:
                                          self._round - r.submit_round)
                                         for r in self.queues[i]))
                  for i in active]
-        cached = (self.session.store.occupancies()
-                  if self.session is not None else ())
-        ids = self.composer.compose(views, self.clock_s, self._probe(),
-                                    cached_occupancies=cached)
+        ids = self.composer.compose(
+            views, self.clock_s, self._probe(),
+            cached_occupancies=self.session.store.occupancies())
         return ids if ids else active
 
     # -- dispatch -----------------------------------------------------------
@@ -448,11 +441,11 @@ class MultiModelEngine:
     def _resolve_plan(self, ids: List[int],
                       shapes: Optional[Mapping[int, int]] = None):
         """The round's occupancy plan at the given bucket vector, or
-        ``None`` for a floor/fallback round.  With a background compiler
-        attached the lookup never compiles: a miss enqueues the compile
-        and this round serves the compile-alone concat floor.  The
-        ``repro.plan`` span's ``hit`` is false when the store did not hold
-        the plan."""
+        ``None`` for a floor round.  With a background compiler attached
+        the lookup never compiles: a miss enqueues the compile and this
+        round serves the compile-alone concat floor.  Without one, a miss
+        compiles the plan here.  The ``repro.plan`` span's ``hit`` is
+        false when the store did not hold the plan."""
         with span(PLAN) as sp:
             if self.compiler is not None:
                 # every dispatched lattice point (hit or miss) anchors the
@@ -463,33 +456,23 @@ class MultiModelEngine:
                 if plan is None:
                     self.compiler.submit(key)
                 sp.set_metadata(hit=plan is not None)
-                return plan, plan is None          # floor round on miss
-            store = getattr(self.session, "store", None)
-            misses = store.misses if store is not None else 0
+                return plan
+            misses = self.session.store.misses
             plan = self.compiled.plan_for(ids, shapes=shapes)
-            sp.set_metadata(hit=plan is not None and (
-                store is None or store.misses == misses))
-            return plan, False
+            sp.set_metadata(hit=self.session.store.misses == misses)
+            return plan
 
     def _param_dma_in_cycles(self, plan) -> float:
         """DMA cycles this plan spends loading parameter tensors — the
         traffic a back-to-back re-execution of the same plan skips
         (weights already resident in shared L2)."""
-        tenants = getattr(plan, "tenants", None)
-        if tenants is None:
-            return 0.0
         total = 0.0
         for d in plan.dmas:
-            if d.direction != "in":
+            parsed = split_namespaced(d.tensor)
+            if d.direction != "in" or parsed is None:
                 continue
-            name = d.tensor
-            if "/" not in name or not name.startswith("t"):
-                continue
-            idx, _, base = name.partition("/")
-            try:
-                ti = tenants[int(idx[1:])].graph.tensors.get(base)
-            except (ValueError, IndexError):
-                continue
+            tenant, base = parsed
+            ti = plan.tenants[tenant].graph.tensors.get(base)
             if ti is not None and ti.kind == "param":
                 total += d.end - d.start
         return total
@@ -609,7 +592,7 @@ class MultiModelEngine:
         round_start = self.clock_s
         # the bucket vector of the heads this wave pops — resolved BEFORE
         # popping, so the plan lookup and the pop see the same EDF picks
-        plan, floor = self._resolve_plan(ids, self._head_shapes(ids))
+        plan = self._resolve_plan(ids, self._head_shapes(ids))
         if plan is not None:
             # positions in the occupancy plan follow sorted tenant ids,
             # which is the order ``ids`` arrives in
@@ -641,27 +624,19 @@ class MultiModelEngine:
                              len(reqs) > 1, outs[pos], completed)
             self.clock_s = round_start + self._cycles_to_s(round_cycles)
             return plan
-        # floor (async miss) or fallback (session-less partial occupancy):
-        # single-model schedules back-to-back; each request's latency
-        # includes the in-round wait behind the tenants dispatched before
-        # it (consistent with the co-scheduled path, which charges
-        # tenant_makespans[pos]).  The async floor runs the compile-alone
-        # schedules — the hard floor the pending subset plan is
-        # guaranteed to beat or tie — while the legacy session-less
-        # fallback keeps the reference (tenant_plan) schedules.
-        if floor:
-            self.floor_rounds += 1
-        else:
-            self.fallback_rounds += 1
+        # floor round (background-compile miss): the compile-alone
+        # schedules back-to-back — the hard floor the pending subset plan
+        # is guaranteed to beat or tie; each request's latency includes
+        # the in-round wait behind the tenants dispatched before it
+        # (consistent with the plan round, which charges
+        # tenant_makespans[pos])
+        self.floor_rounds += 1
         round_offset = 0.0
         for i in ids:
             r = self._pop_head(i)
-            if floor:
-                splan = (self.session.bucket_single(i, r.bucket).plan
-                         if r.bucket is not None
-                         else self.compiled.singles[i].plan)
-            else:
-                splan = self.compiled.tenant_plan(i)
+            splan = (self.session.bucket_single(i, r.bucket).plan
+                     if r.bucket is not None
+                     else self.compiled.singles[i].plan)
             out = None
             if self.execute:
                 out = _executed(1, lambda: runtime.execute_plan(
@@ -671,7 +646,7 @@ class MultiModelEngine:
             round_offset += splan.makespan
             self._finish(r, round_start + self._cycles_to_s(round_offset),
                          self.soc.cycles_to_ms(round_offset),
-                         False, out, completed, floor=floor)
+                         False, out, completed, floor=True)
         self.clock_s = round_start + self._cycles_to_s(round_offset)
         return None
 
@@ -791,10 +766,6 @@ class MultiModelEngine:
                 "mean_wait_rounds": (sum(r.wait_rounds for r in reqs)
                                      / len(reqs) if reqs else 0.0),
             })
-        stats = (self.compiled.store_stats()
-                 if hasattr(self.compiled, "store_stats") else None)
-        joint = (self.compiled.joint_stats()
-                 if hasattr(self.compiled, "joint_stats") else None)
         with_dl = [r for r in self.done.values()
                    if r.deadline_met is not None]
         return {
@@ -804,18 +775,14 @@ class MultiModelEngine:
             "co_rounds": self.co_rounds,
             "subset_co_rounds": self.subset_co_rounds,
             "solo_rounds": self.solo_rounds,
-            "fallback_rounds": self.fallback_rounds,
             "floor_rounds": self.floor_rounds,
             "batched_repeat_rounds": self.batched_repeat_rounds,
             "solo_dispatches": self.solo_dispatches,
-            "plan_store": stats,
-            "joint_cp": joint,
-            "solver": (self.session.solver_stats()
-                       if self.session is not None else None),
-            "compile_latency": (self.session.compile_latency_stats()
-                                if self.session is not None else None),
-            "analysis": (self.session.analysis_stats()
-                         if self.session is not None else None),
+            "plan_store": self.compiled.store_stats(),
+            "joint_cp": self.compiled.joint_stats(),
+            "solver": self.session.solver_stats(),
+            "compile_latency": self.session.compile_latency_stats(),
+            "analysis": self.session.analysis_stats(),
             "throughput_inf_per_s": served / secs if secs else 0.0,
             "speedup_vs_sequential": self.compiled.speedup,
             "retiled": self.compiled.retiled,

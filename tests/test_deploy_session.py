@@ -12,6 +12,7 @@ import pytest
 
 from _hypo import given, settings, st
 
+from repro.analysis import analyze_errors
 from repro.core.api import compile_model, compile_multi
 from repro.core.deploy import (ASYNC_MODES, STRATEGY_REGISTRY, CandidateSpec,
                                CompileRequest, DeploymentSession, Objective,
@@ -19,8 +20,7 @@ from repro.core.deploy import (ASYNC_MODES, STRATEGY_REGISTRY, CandidateSpec,
                                get_strategy)
 from repro.core.runtime import (execute_multi_plan, execute_plan,
                                 init_inputs, init_params)
-from repro.core.schedule import (MultiExecutionPlan,
-                                 validate_multi_schedule)
+from repro.core.schedule import MultiExecutionPlan
 from repro.soc.testbed import dense_chain, two_acc_soc
 
 REQUESTED_TILES = 4
@@ -67,7 +67,7 @@ def test_plan_for_answers_every_two_tenant_subset(mc, session):
         plan = mc.plan_for(ids)
         assert isinstance(plan, MultiExecutionPlan)
         assert len(plan.tenants) == len(ids)
-        assert validate_multi_schedule(plan) == []
+        assert not analyze_errors(plan)
         for pos, i in enumerate(ids):
             ref = session.reference_plan(i, plan.tenants[pos])
             assert ref.tiled is plan.tenants[pos]
@@ -111,7 +111,7 @@ def test_plan_for_full_house_is_the_compiled_plan(mc):
 def test_plan_for_singleton(mc):
     for i in range(len(mc.graphs)):
         plan = mc.plan_for([i])
-        assert validate_multi_schedule(plan) == []
+        assert not analyze_errors(plan)
         assert plan.makespan <= mc.tenant_plan(i).makespan + 1e-6
 
 
@@ -120,14 +120,6 @@ def test_plan_for_rejects_bad_occupancy(session, mc):
         session.plan_for([])
     with pytest.raises(ValueError):
         session.plan_for([0, 99])
-
-
-def test_sessionless_artifact_keeps_legacy_none(mc):
-    """A hand-built artifact without a session preserves the legacy
-    contract: full house answered, partial occupancy -> None."""
-    legacy = dataclasses.replace(mc, session=None)
-    assert legacy.plan_for(range(len(mc.graphs))) is mc.plan
-    assert legacy.plan_for([0, 1]) is None
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +357,7 @@ def test_subset_coschedule_properties(data):
                        time_budget_s=TIME_BUDGET_S)
     for ids in two_subsets(n_tenants):
         plan = mc.plan_for(ids)
-        assert validate_multi_schedule(plan) == []
+        assert not analyze_errors(plan)
         seq = sum(mc.tenant_plan(i).makespan for i in ids)
         assert plan.makespan <= seq + 1e-6
         # second lookup is a cache hit: same object

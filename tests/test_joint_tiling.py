@@ -12,6 +12,7 @@ import pytest
 
 from _hypo import given, settings, st
 
+from repro.analysis import analyze_errors
 from repro.core import cpsolver
 from repro.core.api import compile_multi
 from repro.core.deploy import (CompileRequest, DeploymentSession, Objective,
@@ -20,7 +21,6 @@ from repro.core.deploy import (CompileRequest, DeploymentSession, Objective,
 from repro.core.rewrite import rewrite
 from repro.core.runtime import (execute_multi_plan, execute_plan,
                                 init_inputs, init_params)
-from repro.core.schedule import validate_multi_schedule
 from repro.core.tiling import (JointTilingProblem, conservation_ok,
                                optimize_tiling)
 from repro.soc.testbed import dense_chain, two_acc_soc
@@ -211,7 +211,7 @@ def test_joint_property_random_mixes(data):
     assert_ordering(mc)
     for ids in ([i] for i in range(n)):
         plan = mc.plan_for(ids)
-        assert validate_multi_schedule(plan) == []
+        assert not analyze_errors(plan)
         alone = sum(mc.singles[i].plan.makespan for i in ids)
         assert plan.makespan <= alone + 1e-6
 
@@ -234,7 +234,7 @@ def test_every_occupancy_beats_compile_alone_concat(mc):
     compile-alone schedules back-to-back."""
     for ids in all_subsets(len(mc.graphs)):
         plan = mc.plan_for(ids)
-        assert validate_multi_schedule(plan) == []
+        assert not analyze_errors(plan)
         alone = sum(mc.singles[i].plan.makespan for i in ids)
         assert plan.makespan <= alone + 1e-6, (ids, plan.makespan, alone)
 
@@ -278,7 +278,7 @@ def test_joint_timeout_engages_best_response_fallback():
     mc = s.compile()
     assert s.joint_fallbacks >= 1
     assert s.joint_solves == 0
-    assert validate_multi_schedule(mc.plan) == []
+    assert not analyze_errors(mc.plan)
     assert_ordering(mc)
     assert mc.joint_stats()["fallbacks"] == s.joint_fallbacks
 
@@ -287,7 +287,7 @@ def test_joint_disabled_contributes_nothing():
     s = three_tenant_session(joint_tiling=False)
     mc = s.compile()
     assert s.joint_solves == 0 and s.joint_fallbacks == 0
-    assert validate_multi_schedule(mc.plan) == []
+    assert not analyze_errors(mc.plan)
 
 
 def test_joint_fallback_delegates_when_sole_retiler():
@@ -299,7 +299,7 @@ def test_joint_fallback_delegates_when_sole_retiler():
         joint_time_budget_s=0.0)
     mc = s.compile()
     assert s.joint_fallbacks >= 1
-    assert validate_multi_schedule(mc.plan) == []
+    assert not analyze_errors(mc.plan)
 
 
 # ---------------------------------------------------------------------------
@@ -445,7 +445,7 @@ def test_objective_chain_threads_through_schedule_multi():
     s.objective = Objective(tie_breaks=("evictions", "dma_bytes",
                                         "retile_rounds"))
     mc = s.compile()
-    assert validate_multi_schedule(mc.plan) == []
+    assert not analyze_errors(mc.plan)
     assert_ordering(mc)
 
 

@@ -2,8 +2,8 @@
 
 from _hypo import given, settings, st
 
-from repro.core.memplan import (Allocation, L2Allocator, MemoryPlan,
-                                validate_plan)
+from repro.analysis import analyze_errors
+from repro.core.memplan import Allocation, L2Allocator, MemoryPlan
 
 
 @settings(max_examples=50, deadline=None)
@@ -31,7 +31,7 @@ def test_allocator_never_overlaps(reqs):
         alloc.free(name, t_end)
     plan = MemoryPlan(capacity=alloc.capacity, allocations=alloc.history,
                       swaps=[], peak=alloc.peak)
-    assert validate_plan(plan) == []
+    assert not analyze_errors(plan)
     assert alloc.used() == 0
 
 

@@ -9,11 +9,11 @@ random 2-3-device SoCs (via the _hypo shim):
 
 from _hypo import given, settings, st
 
-from repro.core.memplan import validate_plan
+from repro.analysis import analyze_errors
 from repro.core.patterns import chain, wildcard
 from repro.core.rewrite import rewrite
 from repro.core.schedule import (_upward_rank, build_dag, default_budgets,
-                                 schedule_multi, validate_multi_schedule)
+                                 schedule_multi)
 from repro.core.ir import Graph
 from repro.core.tiling import optimize_tiling
 from repro.soc.device import Device, MemoryLevel, SoC
@@ -83,10 +83,10 @@ def test_coschedule_invariants(data):
     plan = schedule_multi(tgs, soc)
 
     # precedence + per-device / per-DMA mutual exclusion
-    assert validate_multi_schedule(plan) == []
+    assert not analyze_errors(plan)
 
     # shared-L2 occupancy: overlap-free rectangles within capacity
-    assert validate_plan(plan.memory) == []
+    assert not analyze_errors(plan.memory)
     assert plan.memory.peak <= soc.l2.size
 
     # per-tenant makespan >= that tenant's critical path
@@ -139,8 +139,8 @@ def test_contention_eviction_packing_stays_valid():
                               requested_tiles=2, time_budget_s=0.5)
         tgs.append(rewrite(g, soc, sol))
     plan = schedule_multi(tgs, soc)
-    assert validate_multi_schedule(plan) == []
-    assert validate_plan(plan.memory) == []
+    assert not analyze_errors(plan)
+    assert not analyze_errors(plan.memory)
     assert plan.memory.peak <= soc.l2.size
     evictions = [s for s in plan.memory.swaps if s.direction == "out"]
     assert evictions, "scenario must actually exercise eviction traffic"

@@ -4,12 +4,11 @@ single-model oracle, and the serving engine on top of the co-schedule."""
 import numpy as np
 import pytest
 
+from repro.analysis import analyze_errors
 from repro.core.api import compile_multi
-from repro.core.memplan import validate_plan
 from repro.core.runtime import (execute_multi_plan, execute_plan,
                                 init_inputs, init_params,
                                 multi_plan_matches_oracle)
-from repro.core.schedule import validate_multi_schedule
 from repro.models import edge
 from repro.serve.engine import MultiModelEngine
 from repro.soc.carfield import carfield_patterns, carfield_soc
@@ -39,8 +38,8 @@ def test_coscheduled_makespan_beats_sequential(golden_mc):
 
 
 def test_coschedule_is_feasible(golden_mc):
-    assert validate_multi_schedule(golden_mc.plan) == []
-    assert validate_plan(golden_mc.plan.memory) == []
+    assert not analyze_errors(golden_mc.plan)
+    assert not analyze_errors(golden_mc.plan.memory)
     assert golden_mc.plan.memory.peak <= SOC.l2.size
 
 
@@ -79,7 +78,7 @@ def test_plan_for_partial_occupancy_no_fallback(golden_mc):
     for active in ([0], [1]):
         plan = golden_mc.plan_for(active)
         assert plan is not None
-        assert validate_multi_schedule(plan) == []
+        assert not analyze_errors(plan)
         assert plan.makespan <= \
             golden_mc.tenant_plan(active[0]).makespan + 1e-6
     assert golden_mc.plan_for([0, 1]) is golden_mc.plan

@@ -1,6 +1,6 @@
 """Static plan analyzer (PR 7): clean plans analyze clean at strict,
-every rule's seeded mutation is caught (the analyzer has teeth), the
-legacy validators are analyzer shims, the deployment session enforces
+every rule's seeded mutation is caught (the analyzer has teeth), on
+multi, single and bare memory plans, the deployment session enforces
 the strict/warn knob, and the concurrency lint holds on the serving
 layer."""
 
@@ -15,8 +15,6 @@ from repro.analysis.mutate import MUTATORS, check_rules, clone_plan, mutate
 from repro.analysis.scan_mixes import mixes_from_baseline, plans_for_mix
 from repro.core.api import compile_multi
 from repro.core.deploy import CompileRequest, DeploymentSession
-from repro.core.memplan import validate_plan
-from repro.core.schedule import validate_multi_schedule, validate_schedule
 from repro.soc.testbed import dense_chain, two_acc_soc
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -123,33 +121,27 @@ def test_clone_plan_is_deep_enough(mc):
 
 
 # ---------------------------------------------------------------------------
-# Legacy validators are analyzer shims
+# analyze_errors names the rule, on every plan shape
 # ---------------------------------------------------------------------------
 
 
-def test_validators_flag_mutations_with_rule_ids(mc):
-    assert validate_multi_schedule(mc.plan) == []
-    errs = validate_multi_schedule(mutate(mc.plan, "PA001"))
-    assert errs and any("PA001" in e for e in errs)
-    single = mc.singles[0].plan
-    assert validate_schedule(single) == []
-    errs = validate_schedule(mutate(single, "PA002"))
-    assert errs and any("PA002" in e for e in errs)
-
-
-def test_multi_validator_now_checks_l2_aliasing(mc):
-    """PR-7 coverage gain: ``validate_multi_schedule`` flags L2 address
-    aliasing between concurrently-live allocations (it only checked
-    precedence/overlap/residency before)."""
-    errs = validate_multi_schedule(mutate(mc.plan, "PA005"))
-    assert errs and any("PA005" in e for e in errs)
-
-
-def test_memplan_validator_shares_analyzer_epsilon(mc):
-    mem = mc.plan.memory
-    assert validate_plan(mem) == []
-    errs = validate_plan(mutate(mc.plan, "PA005").memory)
-    assert errs and any("PA005" in e for e in errs)
+@pytest.mark.parametrize("shape, rule", [
+    ("multi", "PA001"),
+    ("single", "PA002"),
+    ("multi", "PA005"),      # L2 address aliasing across tenants
+    ("memory", "PA005"),     # a bare MemoryPlan
+])
+def test_analyze_errors_names_the_mutated_rule(mc, shape, rule):
+    """A clean plan has no findings; its mutation is an ERROR that names
+    the rule, whether the analyzer gets a co-schedule, a single-model
+    plan or a bare memory plan."""
+    plan = mc.singles[0].plan if shape == "single" else mc.plan
+    bad = mutate(plan, rule)
+    if shape == "memory":
+        plan, bad = plan.memory, bad.memory
+    assert not analyze_errors(plan)
+    errs = [str(d) for d in analyze_errors(bad)]
+    assert errs and any(rule in e for e in errs)
 
 
 # ---------------------------------------------------------------------------

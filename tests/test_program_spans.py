@@ -37,8 +37,8 @@ NAMES = {spans.SUBMIT, spans.STEP, spans.WAVE, spans.PLAN, spans.EXECUTE}
 PARENT = {spans.WAVE: spans.STEP, spans.PLAN: spans.WAVE,
           spans.EXECUTE: spans.WAVE}
 COUNTERS = ("rounds", "co_rounds", "subset_co_rounds", "solo_rounds",
-            "floor_rounds", "fallback_rounds", "batched_repeat_rounds",
-            "solo_dispatches", "busy_cycles", "clock_s")
+            "floor_rounds", "batched_repeat_rounds", "solo_dispatches",
+            "busy_cycles", "clock_s")
 
 
 def make_session() -> DeploymentSession:

@@ -8,12 +8,13 @@ import pytest
 
 from _hypo import given, settings, st
 
+from repro.analysis import analyze_errors
 from repro.core.api import compile_multi
 from repro.core.runtime import (execute_multi_plan, execute_plan,
                                 init_inputs, init_params,
                                 multi_plan_matches_oracle)
 from repro.core.schedule import (_search_coschedule, contention_hints,
-                                 default_budgets, validate_multi_schedule)
+                                 default_budgets)
 from repro.core.tiling import Contention
 from repro.soc.testbed import dense_chain, forced_contention_setup, \
     two_acc_soc
@@ -56,7 +57,7 @@ def test_forced_contention_retiling_reduces_evictions(forced_contention_mc):
 
 def test_forced_contention_plan_feasible(forced_contention_mc):
     mc, soc = forced_contention_mc
-    assert validate_multi_schedule(mc.plan) == []
+    assert not analyze_errors(mc.plan)
     assert mc.plan.memory.peak <= soc.l2.size
 
 
@@ -128,4 +129,4 @@ def test_retile_makespan_dominance_chain(data):
     assert mc.plan.makespan <= mc.baseline_makespan_cycles + 1e-6
     assert mc.baseline_makespan_cycles <= \
         mc.sequential_makespan_cycles + 1e-6
-    assert validate_multi_schedule(mc.plan) == []
+    assert not analyze_errors(mc.plan)

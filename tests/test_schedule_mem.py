@@ -2,9 +2,8 @@
 
 import pytest
 
+from repro.analysis import analyze_errors
 from repro.core.api import compile_model
-from repro.core.memplan import validate_plan
-from repro.core.schedule import validate_schedule
 from repro.models import edge
 from repro.soc.carfield import carfield_patterns, carfield_soc
 
@@ -21,16 +20,16 @@ PATS = carfield_patterns()
 def test_schedule_constraints(model, mode):
     cm = compile_model(edge.ALL_MODELS[model](), SOC, PATS, mode=mode,
                        time_budget_s=2.0)
-    errs = validate_schedule(cm.plan)
-    assert errs == [], errs
+    errs = analyze_errors(cm.plan)
+    assert not errs, errs
 
 
 @pytest.mark.parametrize("model", ["autoencoder", "resnet", "mobilenet"])
 def test_memory_plan_valid(model):
     cm = compile_model(edge.ALL_MODELS[model](), SOC, PATS, mode="matcha",
                        time_budget_s=2.0)
-    errs = validate_plan(cm.plan.memory)
-    assert errs == [], errs
+    errs = analyze_errors(cm.plan.memory)
+    assert not errs, errs
     assert cm.plan.memory.peak <= SOC.l2.size
 
 

@@ -8,6 +8,8 @@ import random
 
 import pytest
 
+from repro.core.schedule import namespaced, split_namespaced
+
 
 @pytest.fixture(scope="module")
 def multi_mc():
@@ -22,6 +24,15 @@ def multi_mc():
         requested_tiles=4, time_budget_s=0.5)).compile()
 
 
+@pytest.fixture(scope="module")
+def contention_mc():
+    from repro.core.api import compile_multi
+    from repro.soc.testbed import forced_contention_setup
+    soc, pats, graphs = forced_contention_setup()
+    return compile_multi(graphs, soc, pats, requested_tiles=8,
+                         time_budget_s=0.5)
+
+
 def test_mixed_class_soak_report_invariants(multi_mc):
     """Seeded soak: >= 200 mixed-class requests over 3 tenants with
     arrivals and departures (idle service rounds drain queues between
@@ -29,9 +40,8 @@ def test_mixed_class_soak_report_invariants(multi_mc):
 
       * per-class served + rejected == submitted, for every class and in
         aggregate;
-      * round decomposition: co_rounds + solo_rounds + fallback_rounds +
-        floor_rounds == rounds (subset co-rounds are a sub-count of
-        co_rounds);
+      * round decomposition: co_rounds + solo_rounds + floor_rounds ==
+        rounds (subset co-rounds are a sub-count of co_rounds);
       * no negative latencies, waits, or clocks anywhere.
     """
     from repro.serve.admission import (AdmissionController, ClassPolicy,
@@ -68,9 +78,8 @@ def test_mixed_class_soak_report_invariants(multi_mc):
 
     # round decomposition closes
     assert rep["rounds"] == rep["co_rounds"] + rep["solo_rounds"] + \
-        rep["fallback_rounds"] + rep["floor_rounds"]
+        rep["floor_rounds"]
     assert rep["subset_co_rounds"] <= rep["co_rounds"]
-    assert rep["fallback_rounds"] == 0      # session-backed artifact
 
     # no negative latencies / waits / clocks
     for r in eng.done.values():
@@ -82,19 +91,16 @@ def test_mixed_class_soak_report_invariants(multi_mc):
     assert rep["starvation_events"] == 0
 
 
-def test_batched_co_round_slots_beat_unbatched_on_bursty_trace():
+def test_batched_co_round_slots_beat_unbatched_on_bursty_trace(
+        contention_mc):
     """max_batch > 1 drains bursts in back-to-back waves inside the
     round; consecutive waves re-running the same plan pay the weights-
     resident repeat cost, so aggregate throughput on a bursty trace is
     pinned >= the unbatched engine (strictly better whenever the plan
     has parameter-load DMA traffic to save — the forced-contention mix
     does)."""
-    from repro.core.api import compile_multi
     from repro.serve.engine import MultiModelEngine
-    from repro.soc.testbed import forced_contention_setup
-    soc, pats, graphs = forced_contention_setup()
-    mc = compile_multi(graphs, soc, pats, requested_tiles=8,
-                       time_budget_s=0.5)
+    mc = contention_mc
 
     def bursty(engine):
         for _ in range(4):                       # a burst per tenant
@@ -136,3 +142,27 @@ def test_batched_waves_keep_fifo_order_and_outputs(multi_mc):
         for t in multi_mc.graphs[0].outputs:
             assert np.array_equal(np.asarray(ref.results[a][t]),
                                   np.asarray(got.results[b][t]))
+
+
+@pytest.mark.parametrize("name, want", [
+    (namespaced(3, "conv1.w"), (3, "conv1.w")),
+    (namespaced(12, "a/b"), (12, "a/b")),
+    ("dma_in", None),
+    ("t/x", None),
+    ("tx/y", None),
+    ("t-1/x", None),
+])
+def test_split_namespaced(name, want):
+    """The parser inverts the builder and rejects any other name."""
+    assert split_namespaced(name) == want
+
+
+def test_repeat_discount_saves_namespaced_param_loads(contention_mc):
+    """The repeat discount finds each parameter load through the
+    namespaced tensor names: the forced-contention plan saves 19968
+    DMA-in cycles of its 187872.67-cycle makespan."""
+    from repro.serve.engine import MultiModelEngine
+    eng = MultiModelEngine(contention_mc, execute=False)
+    assert eng._param_dma_in_cycles(contention_mc.plan) == 19968.0
+    assert eng._repeat_cycles(contention_mc.plan) == \
+        pytest.approx(167904.6666666667)
